@@ -490,7 +490,7 @@ class BeaconApiImpl:
 
 
 def _validator_status(v, epoch: int) -> str:
-    """Condensed validator status (spec status taxonomy)."""
+    """Condensed validator status (spec status categories)."""
     from ..params import FAR_FUTURE_EPOCH
 
     if v.activation_epoch > epoch:

@@ -66,6 +66,12 @@ class CpuBlsVerifier:
     node (VERDICT r2 Missing #4). Falls back to the Python oracle when
     the extension is unavailable or for non-standard set shapes."""
 
+    # sets per pool task: large batches (a supernode slot's 32,768
+    # attestations under audit) spread over the host's cores — the C
+    # pairing releases the GIL (reference: the worker pool sized to
+    # cores, chain/bls/multithread/poolSize.ts)
+    _CHUNK = 256
+
     def _native_verify(self, sets) -> list[bool] | None:
         from .. import native as _native
 
@@ -74,13 +80,30 @@ class CpuBlsVerifier:
         if not all(len(s.signature) == 96 for s in sets):
             return None
         try:
-            pk_b = b"".join(s.pubkey.to_bytes() for s in sets)
+            pks = [s.pubkey.to_bytes() for s in sets]
         except (bls.BlsError, ValueError):
             return None
-        sig_b = b"".join(s.signature for s in sets)
-        return _native.bls_verify_sets(
-            pk_b, [s.message for s in sets], sig_b, bls.DST_G2
-        )
+
+        def verify(lo: int, hi: int) -> list[bool]:
+            return _native.bls_verify_sets(
+                b"".join(pks[lo:hi]), [s.message for s in sets[lo:hi]],
+                b"".join(s.signature for s in sets[lo:hi]), bls.DST_G2,
+            )
+
+        n = len(sets)
+        if n < 2 * self._CHUNK:
+            return verify(0, n)
+        import os
+        from concurrent.futures import ThreadPoolExecutor
+
+        # a pool per large batch: thread start-up is ~1 ms against the
+        # seconds of pairings it spreads
+        with ThreadPoolExecutor(os.cpu_count() or 1) as pool:
+            futs = [
+                pool.submit(verify, lo, min(lo + self._CHUNK, n))
+                for lo in range(0, n, self._CHUNK)
+            ]
+            return [v for f in futs for v in f.result()]
 
     def verify_signature_sets(self, sets) -> bool:
         sets = list(sets)
@@ -134,7 +157,12 @@ class DeviceBlsVerifier:
             buckets=buckets, grouped_configs=grouped_configs, observer=observer
         )
         self.observer = self._inner.observer
-        self.max_sets_per_job = buckets[-1]
+        # a batch job may fill the largest grouped config (rows × lanes
+        # sets); a per-set verdict job fills at most the largest bucket
+        self.max_sets_per_job = max(
+            [buckets[-1]] + [r * l for r, l in grouped_configs]
+        )
+        self.max_sets_per_verdict_job = buckets[-1]
         self._profile_dir = env_str("LODESTAR_TPU_PROFILE")
         self._last_fallback_log = float("-inf")
 
@@ -157,6 +185,11 @@ class DeviceBlsVerifier:
 
     def h2c_cache_size(self) -> int:
         return len(self._inner._h2c_cache)
+
+    def precompile(self) -> list[str]:
+        """Compile every kernel this tier can dispatch ahead of the
+        first batch (parallel/verifier.TpuBlsVerifier.precompile)."""
+        return self._inner.precompile()
 
     # -- mesh passthroughs (supervisor failure policy; parallel/mesh) -------
 
@@ -235,11 +268,12 @@ class DeviceBlsVerifier:
         sets = list(sets)
         self._note_decompress_fallback(sets)
         out: list[bool] = []
+        step = self.max_sets_per_verdict_job
         with self._annotate(f"bls_verify_individual/{len(sets)}"):
-            for i in range(0, len(sets), self.max_sets_per_job):
+            for i in range(0, len(sets), step):
                 out.extend(
                     self._inner.verify_signature_sets_individual(
-                        sets[i : i + self.max_sets_per_job]
+                        sets[i : i + step]
                     )
                 )
         return out

@@ -164,14 +164,25 @@ class BlsLaneDispatcher(ThreadBufferedVerifier):
         sets = list(sets)
         if not sets:
             return False
-        if lane not in LANE_PRIORITY:
-            lane = DEFAULT_LANE
         # latency-critical callers and calls already at batch size skip
         # the queue entirely (base-facade contract, batchable=False)
         if not batchable or len(sets) >= self.max_sigs:
             if self.prom is not None:
                 self.prom.bls_main_thread_sets_total.inc(len(sets))
             return self.verifier.verify_signature_sets(sets)
+        return self.submit(sets, lane)()
+
+    def submit(self, sets, lane: str = DEFAULT_LANE):
+        """Queue one request without waiting for it; returns a zero-arg
+        resolver that blocks until its verdict (raising `BlsShedError`
+        when admission control shed it). `verify_signature_sets` is
+        submit-then-resolve; a caller that holds many requests at once
+        (a gossip burst) submits them all, then resolves each."""
+        sets = list(sets)
+        if not sets:
+            return lambda: False
+        if lane not in LANE_PRIORITY:
+            lane = DEFAULT_LANE
         ev = threading.Event()
         holder: list = [None]
         with self._cv:
@@ -181,9 +192,13 @@ class BlsLaneDispatcher(ThreadBufferedVerifier):
                 shed = self._admit_locked(sets, ev, holder, lane)
                 direct = False
         if direct:
-            return self.verifier.verify_signature_sets(sets)
+            verdict = self.verifier.verify_signature_sets(sets)
+            return lambda: verdict
         if shed is not None:
             raise shed
+        return lambda: self._await(ev, holder, len(sets), lane)
+
+    def _await(self, ev, holder, n_sets: int, lane: str) -> bool:
         if not ev.wait(self.waiter_timeout):
             self.pipeline.waiter_timeout()
             from ..utils.logger import get_logger
@@ -192,7 +207,7 @@ class BlsLaneDispatcher(ThreadBufferedVerifier):
                 "verify waiter gave up after %.1fs: lane workers wedged "
                 "(%d sets, lane=%s); counted in "
                 "lodestar_bls_verifier_waiter_timeouts_total",
-                self.waiter_timeout, len(sets), lane,
+                self.waiter_timeout, n_sets, lane,
             )
             out = holder[0]
             if isinstance(out, BlsShedError):

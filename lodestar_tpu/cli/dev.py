@@ -13,7 +13,6 @@ from ..api import BeaconApiServer
 from ..api.impl import BeaconApiImpl
 from ..bls import api as bls
 from ..chain import BeaconChain, CpuBlsVerifier
-from ..chain.bls_verifier import DeviceBlsVerifier
 from ..config.beacon_config import BeaconConfig, ChainForkConfig
 from ..config.chain_config import MINIMAL_CHAIN_CONFIG
 from ..db import MemoryDb
@@ -45,12 +44,14 @@ def run_dev(args) -> int:
         state.genesis_validators_root.hex()[:16],
     )
 
+    metrics = create_beacon_metrics()
     if args.tpu_verifier:
-        # same supervised stack as BeaconNode.init: device tier behind
-        # the deadline/retry/fallback/breaker policy (docs/robustness.md)
-        from ..chain import SupervisedBlsVerifier
+        # the same stack as BeaconNode.init (chain/verifier_stack.py)
+        from ..chain.verifier_stack import build_verifier_stack
 
-        verifier = SupervisedBlsVerifier(DeviceBlsVerifier(), CpuBlsVerifier())
+        verifier = build_verifier_stack(
+            metrics.pipeline, prom=metrics
+        ).dispatcher
     else:
         verifier = CpuBlsVerifier()
     chain = BeaconChain(config, types, state, verifier=verifier)
@@ -59,7 +60,6 @@ def run_dev(args) -> int:
         store.add_secret_key(bls.interop_secret_key(i))
     service = ValidatorService(config, types, chain, store)
 
-    metrics = create_beacon_metrics()
     chain.metrics = metrics
     # per-validator duty monitor over the local keys (reference
     # validatorMonitor: epoch-end duty summaries + metrics)

@@ -103,52 +103,29 @@ class BeaconNode:
         # the device tier sits behind the cross-thread batching facade so
         # concurrent gossip-queue validations merge into device batches
         if opts.tpu_verifier:
-            # persistent XLA compile cache BEFORE the first kernel trace:
-            # a node restart must hit `tools/warmup.py`'s cached
-            # executables, not recompile the dispatch ladder cold
-            # (LODESTAR_TPU_COMPILE_CACHE overrides/disables)
-            from ..utils.jax_env import enable_compile_cache
+            # the device stack (chain/verifier_stack.py): lane dispatcher
+            # → supervisor (deadlines, retry, CPU-tier fallback, breaker;
+            # docs/robustness.md) → device kernels, on the node registry.
+            # It also turns the persistent compile cache on, loads every
+            # persisted AOT executable for this build, and compiles every
+            # kernel the device tier can dispatch before the verifier is
+            # ready (cold, minutes on a TPU; a first dispatch that
+            # compiled would outlast the supervisor's deadline)
+            from ..chain.verifier_stack import build_verifier_stack
 
-            enable_compile_cache()
-            from ..chain.bls_verifier import DeviceBlsVerifier
-            from ..chain.dispatcher import BlsLaneDispatcher
-            from ..chain.supervisor import SupervisedBlsVerifier
-
-            # pipeline telemetry rides the node registry: stage timers +
-            # planner counters from the device tier, flush/queue gauges
-            # from the batching facade — all on /metrics by default.
-            # The supervisor between them owns the failure policy:
-            # per-dispatch deadlines, one retry, CPU-oracle fallback and
-            # the circuit breaker (docs/robustness.md) — a device outage
-            # degrades throughput instead of rejecting valid blocks
-            self.bls_supervisor = SupervisedBlsVerifier(
-                DeviceBlsVerifier(observer=self.metrics.pipeline),
-                CpuBlsVerifier(),
-                observer=self.metrics.pipeline,
+            stack = build_verifier_stack(
+                self.metrics.pipeline, prom=self.metrics
             )
-            # continuous-batching front-end with priority lanes (block >
-            # sync-committee > aggregate > attestation): coalesces
-            # concurrent gossip verifies, double-buffers host prep
-            # against device compute, and sheds attestations first under
-            # flood (never blocks) — chain/dispatcher.py
-            verifier = BlsLaneDispatcher(
-                self.bls_supervisor, prom=self.metrics,
-            )
-            # crash-safe warm boot (ISSUE 19): load every persisted AOT
-            # executable for this build fingerprint BEFORE declaring the
-            # verifier ready — a restart against a populated store serves
-            # its dispatch ladder without entering XLA at all; a missing/
-            # corrupt store degrades to the normal JIT path (counted, not
-            # fatal)
-            from ..observability.compile_ledger import ledger as _ledger
-
-            aot = _ledger().preload_aot()
-            if aot["loaded"]:
+            self.bls_supervisor = stack.supervisor
+            verifier = stack.dispatcher
+            if stack.aot["loaded"]:
                 self.log.info(
                     "aot store: %d executable(s) loaded in %.1fs "
                     "(restart without XLA in the loop)",
-                    len(aot["loaded"]), aot["seconds"],
+                    len(stack.aot["loaded"]), stack.aot["seconds"],
                 )
+            self.log.info("device verifier kernels ready: %s",
+                          ", ".join(stack.compiled))
             timeline().mark("verifier_ready")
         else:
             self.bls_supervisor = None

@@ -182,7 +182,30 @@ class CompileLedger:
             return self._timed_first_call(fn, kernel, key, args, kwargs)
 
         wrapped.__compile_ledger_kernel__ = kernel
+        wrapped.__compile_ledger_static_key__ = static_key
         return wrapped
+
+    def compile_ahead(self, wrapped, *shapes) -> None:
+        """Compile a `wrap`ped jit callable for argument `shapes`
+        (`jax.ShapeDtypeStruct`s) before its first call, and serve that
+        call from the executable. The same ledger event as a first-call
+        compile (AOT store first, then the persistent cache), minus the
+        dispatch — so several kernels can compile on concurrent threads
+        (XLA compiles release the GIL) instead of one after another on
+        the serving path."""
+        kernel = wrapped.__compile_ledger_kernel__
+        key = wrapped.__compile_ledger_static_key__ or _shape_key(shapes, {})
+        with self._lock:
+            if (kernel, key) in self._seen:
+                return
+            self._seen.add((kernel, key))
+        if self._aot_attempt(kernel, key) is not None:
+            return
+        fn = wrapped.__wrapped__
+        self._timed_compile(
+            kernel, key,
+            lambda: self._keep(kernel, key, fn.lower(*shapes).compile()),
+        )
 
     def _timed_first_call(self, fn, kernel, key, args, kwargs):
         # load-before-compile (ISSUE 19): a persisted AOT executable for
@@ -191,6 +214,14 @@ class CompileLedger:
         exec_ = self._aot_attempt(kernel, key)
         if exec_ is not None:
             return exec_(*args, **kwargs)
+        return self._timed_compile(
+            kernel, key,
+            lambda: self._compile_maybe_export(fn, kernel, key, args, kwargs),
+        )
+
+    def _timed_compile(self, kernel, key, compile_fn):
+        """Run `compile_fn` as one recorded compile event, classified by
+        the persistent cache directory (an entry appeared = miss)."""
         cache_dir = _cache_dir()
         self._ensure_cache_baseline(cache_dir)
         before = _cache_listing(cache_dir)
@@ -199,7 +230,7 @@ class CompileLedger:
         # post-mortem as started-but-unfinished
         flight_recorder.record("compile_start", kernel=kernel, key=key)
         t0 = time.monotonic()
-        out = self._compile_maybe_export(fn, kernel, key, args, kwargs)
+        out = compile_fn()
         duration_s = time.monotonic() - t0
         if cache_dir is None:
             cache = "off"
@@ -218,8 +249,7 @@ class CompileLedger:
         degrades to the plain call: export must never fail a dispatch."""
         from ..ops import aot_store
 
-        st = aot_store.store() if aot_store.export_enabled() else None
-        if st is None or not hasattr(fn, "lower"):
+        if not aot_store.export_enabled() or not hasattr(fn, "lower"):
             return fn(*args, **kwargs)
         try:
             compiled = fn.lower(*args, **kwargs).compile()
@@ -233,25 +263,34 @@ class CompileLedger:
             print(f"aot_store: lower/compile for export failed "
                   f"({kernel}:{key}): {e!r}", file=sys.stderr)
             return fn(*args, **kwargs)
-        t0 = time.monotonic()
-        try:
-            st.save(kernel, key, compiled)
-        except aot_store.AotError as e:
-            flight_recorder.record(
-                "aot_export_failed", kernel=kernel, key=key,
-                stage="save", error=str(e)[:200],
-            )
-            print(f"aot_store: export failed ({kernel}:{key}): {e}",
-                  file=sys.stderr)
-        else:
-            self.note_aot(kernel, key, "export",
-                          seconds=time.monotonic() - t0)
+        return self._keep(kernel, key, compiled)(*args, **kwargs)
+
+    def _keep(self, kernel, key, compiled):
+        """Serve later calls of (kernel, key) from `compiled` — identical
+        semantics, and it keeps an exported artifact an exact record of
+        what this process served — and, in producer mode
+        (LODESTAR_TPU_AOT_EXPORT), serialize it into the AOT store.
+        Export failure is recorded, never raised."""
+        from ..ops import aot_store
+
+        st = aot_store.store() if aot_store.export_enabled() else None
+        if st is not None:
+            t0 = time.monotonic()
+            try:
+                st.save(kernel, key, compiled)
+            except aot_store.AotError as e:
+                flight_recorder.record(
+                    "aot_export_failed", kernel=kernel, key=key,
+                    stage="save", error=str(e)[:200],
+                )
+                print(f"aot_store: export failed ({kernel}:{key}): {e}",
+                      file=sys.stderr)
+            else:
+                self.note_aot(kernel, key, "export",
+                              seconds=time.monotonic() - t0)
         with self._lock:
-            # later calls dispatch the compiled executable directly —
-            # identical semantics, and it keeps the exported artifact an
-            # exact record of what this process served
             self._aot_execs[(kernel, key)] = compiled
-        return compiled(*args, **kwargs)
+        return compiled
 
     # -- AOT store (ISSUE 19) ----------------------------------------------
 

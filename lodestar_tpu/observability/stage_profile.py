@@ -50,7 +50,7 @@ def profile_stages(
     histogram. `batch` must be a multiple of 4 (MSM subset-4 tables)."""
     import jax
 
-    from ..ops import fp, fp2, fp12, msm, pallas_tower
+    from ..ops import fp, fp2, fp12, msm
     from ..ops.g2_decompress import decompress
     from ..ops.pairing import (
         final_exponentiation,
@@ -105,18 +105,6 @@ def profile_stages(
         ),
         rpk[0], rpk[1], msg_x, msg_y,
     )
-
-    if pallas_tower.enabled():
-        # device tag `bls/miller_pallas`: the VMEM-resident tower kernel
-        # on the affine shape it serves (interpret mode off-TPU is far
-        # slower than XLA, so this stage only runs when the knob is on)
-        _, results["miller_pallas"] = timed(
-            "miller_pallas",
-            lambda px, py, qx, qy: pallas_tower.miller_loop_pallas(
-                (px, py), (qx, qy)
-            ),
-            rpk[0], rpk[1], msg_x, msg_y,
-        )
 
     prod, results["product_tree"] = timed("product_tree", fp12.product_tree, fs)
 

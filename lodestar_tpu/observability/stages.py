@@ -1,6 +1,6 @@
 """Stage timers + planner counters + queue gauges for the BLS pipeline.
 
-The stage taxonomy follows the verification dataflow (docs/observability.md):
+The stage catalogue follows the verification dataflow (docs/observability.md):
 
 host stages (timed inline, monotonic clock):
     marshal        wire bytes -> limb arrays (C tier decompress/subgroup)
@@ -17,9 +17,7 @@ fused kernel for XLA profiles, and `stage_profile.profile_stages` timing
 per-stage sub-kernels into the SAME histogram for the bench breakdown):
     g2_decompress, scalar_mul, msm_planes, miller_loop, product_tree,
     final_exp, final_exp_batch (batched shared-inversion final exp, device
-    tag `bls/final_exp_batch`), miller_pallas (VMEM-resident Pallas Miller
-    tower when LODESTAR_TPU_PALLAS_MILLER resolves on, device tag
-    `bls/miller_pallas`)
+    tag `bls/final_exp_batch`)
 
 All families live in a `metrics.registry.MetricsRegistry` so they render
 on `/metrics` next to the rest of the node's families. `default_pipeline()`
@@ -53,7 +51,6 @@ STAGES = (
     "scalar_mul",
     "msm_planes",
     "miller_loop",
-    "miller_pallas",
     "product_tree",
     "final_exp",
     "final_exp_batch",

@@ -34,13 +34,15 @@ File layout (one file per (kernel, key, fingerprint)):
               version; a future format bump reads as version_mismatch,
               not corruption)
     4 bytes   big-endian header length
-    N bytes   JSON header {kernel, key, fingerprint{...}, payload_sha256,
-              payload_len, created_unix}
+    N bytes   JSON header {kernel, key, device_ids, fingerprint{...},
+              payload_sha256, payload_len, created_unix} — device_ids
+              are the devices the executable was compiled for, and the
+              only ones it is loaded onto
     M bytes   pickle of (serialized_executable_bytes, in_tree, out_tree)
               — the `serialize_executable.serialize` triple
 
 The store location honors LODESTAR_TPU_AOT_STORE (0/off/none disables;
-unset = the repo-local `.aot_store` next to `.jax_cache`); load and
+unset = `default_store_dir()`, which follows the compile cache); load and
 export are independently gated by LODESTAR_TPU_AOT_LOAD (default on)
 and LODESTAR_TPU_AOT_EXPORT (the producer mode `tools/warmup.py
 --aot-export` sets). The compile ledger (observability/compile_ledger)
@@ -155,11 +157,17 @@ class AotStore:
                 (payload_bytes, in_tree, out_tree),
                 protocol=pickle.HIGHEST_PROTOCOL,
             )
+            # the executable's own device assignment: loading must bind
+            # exactly these devices, not every local one
+            device_ids = [
+                d.id for d in compiled.runtime_executable().local_devices()
+            ]
         except Exception as e:
             raise AotCorrupt(f"serialize failed: {e!r}") from e
         header = {
             "kernel": kernel,
             "key": key,
+            "device_ids": device_ids,
             "fingerprint": self.current_fingerprint(),
             "payload_sha256": hashlib.sha256(payload).hexdigest(),
             "payload_len": len(payload),
@@ -256,6 +264,14 @@ class AotStore:
         sha = hashlib.sha256(payload).hexdigest()
         if sha != header.get("payload_sha256"):
             raise AotCorrupt("payload checksum mismatch")
+        import jax
+
+        by_id = {d.id: d for d in jax.devices()}
+        device_ids = header.get("device_ids") or []
+        if not device_ids or any(i not in by_id for i in device_ids):
+            raise AotVersionMismatch(
+                f"built for devices {device_ids}, visible {sorted(by_id)}"
+            )
         # checksum verified: the pickle below is the bytes the exporter
         # wrote, bit-for-bit
         try:
@@ -263,7 +279,8 @@ class AotStore:
             from jax.experimental import serialize_executable
 
             loaded = serialize_executable.deserialize_and_load(
-                payload_bytes, in_tree, out_tree
+                payload_bytes, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids],
             )
         except Exception as e:
             raise AotCorrupt(f"deserialize failed: {e!r}") from e
@@ -321,7 +338,12 @@ class AotStore:
 
 
 def default_store_dir() -> str:
-    """The repo-local `.aot_store`, sibling of `.jax_cache`."""
+    """The store beside the compile cache: `aot_store/` inside
+    `JAX_COMPILATION_CACHE_DIR` when that is set, else the repo-local
+    `.aot_store` (sibling of `.jax_cache`)."""
+    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if cache:
+        return os.path.join(os.path.abspath(cache), "aot_store")
     return os.path.abspath(
         os.path.join(os.path.dirname(os.path.abspath(__file__)),
                      "..", "..", ".aot_store")

@@ -42,21 +42,29 @@ def tree_sum(curve, p):
     """log-depth complete-add reduction over the last batch axis.
 
     p: (X, Y, Z) projective with shape (..., n, *coord) → (..., *coord).
+    The levels run as one loop over a fixed shape (n padded to a power
+    of two with infinity, each level's sums refilled to full width with
+    it): the point addition is compiled once, not once per level.
     """
-    n = p[0].shape[-1 - curve.coord_ndim]
-    while n > 1:
-        half = n // 2
-        a = tuple(_bsl(curve, c, slice(0, half)) for c in p)
-        b = tuple(_bsl(curve, c, slice(half, 2 * half)) for c in p)
-        s = curve.add(a, b)
-        if n % 2:
-            tail = tuple(_bsl(curve, c, slice(2 * half, n)) for c in p)
-            s = tuple(
-                jnp.concatenate([sc, tc], axis=-1 - curve.coord_ndim)
-                for sc, tc in zip(s, tail)
+    cn = curve.coord_ndim
+    n = p[0].shape[-1 - cn]
+    m = 1 << max(0, (n - 1).bit_length())
+    axis = p[0].ndim - 1 - cn
+    batch = p[0].shape[:axis]
+    if m > n:
+        pad = curve.infinity(batch + (m - n,))
+        p = tuple(jnp.concatenate([c, f], axis) for c, f in zip(p, pad))
+    if m > 1:
+        fill = curve.infinity(batch + (m // 2,))
+
+        def level(_, x):
+            s = curve.add(
+                tuple(_bsl(curve, c, slice(0, None, 2)) for c in x),
+                tuple(_bsl(curve, c, slice(1, None, 2)) for c in x),
             )
-        p = s
-        n = p[0].shape[-1 - curve.coord_ndim]
+            return tuple(jnp.concatenate([c, f], axis) for c, f in zip(s, fill))
+
+        p = lax.fori_loop(0, m.bit_length() - 1, level, p)
     return tuple(_bsl(curve, c, 0) for c in p)
 
 

@@ -37,11 +37,11 @@ from .limbs import LIMB_BITS, LIMB_MASK, N_LIMBS, N0, P_LIMBS
 LANES = 128
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU. Backend errors propagate:
+    a host whose accelerator fails to come up must not be mistaken for a
+    CPU host (and silently run Pallas kernels in interpreter mode)."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def _shift_rows(x, down: int, total: int):
@@ -132,7 +132,7 @@ def mont_mul(a: jnp.ndarray, b: jnp.ndarray, interpret: bool | None = None) -> j
     Accepts the framework layout `(..., N_LIMBS)` with broadcastable batch
     axes; same [0,2p) lazy-reduction contract as fp.mul."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     batch = jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1])
     a = jnp.broadcast_to(a, batch + (N_LIMBS,)).reshape(-1, N_LIMBS)
     b = jnp.broadcast_to(b, batch + (N_LIMBS,)).reshape(-1, N_LIMBS)

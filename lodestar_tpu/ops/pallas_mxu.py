@@ -45,6 +45,7 @@ from jax.experimental import pallas as pl
 
 from ..bls.fields import P as _P_INT
 from .limbs import LIMB_BITS, LIMB_MASK, N_LIMBS, P_LIMBS, R_MONT, int_to_limbs
+from .pallas_fp import on_tpu
 
 _NPRIME_LIMBS = int_to_limbs((-pow(_P_INT, -1, R_MONT)) % R_MONT)
 
@@ -185,13 +186,6 @@ def _mxu_kernel(a_ref, b_ref, s_ref, tn_ref, tp_ref, out_ref):
     out_ref[...] = summed[:, n:]
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
-
-
 @functools.partial(jax.jit, static_argnames=("interpret", "tile"))
 def _mxu_tiles(a: jnp.ndarray, b: jnp.ndarray, interpret: bool, tile: int):
     """a, b: (batch_padded, 32) int32, batch_padded % tile == 0."""
@@ -242,7 +236,7 @@ def mont_mul(
     batch axes, [0, 2p) lazy-reduction contract. Batches smaller than the
     launch-latency break-even fall back to the word-serial scan."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     batch = jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1])
     n_flat = 1
     for d in batch:

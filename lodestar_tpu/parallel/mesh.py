@@ -268,6 +268,14 @@ class BlsMeshDispatcher:
                 self._verifiers[key] = v
             return v, chips, rows
 
+    def compile_target(self, kind: str, shape):
+        """(ledger-wrapped jit entry, input sharding) of the sharded
+        verifier that a `kind` batch of `shape` dispatches to on the
+        current serving mesh: what `TpuBlsVerifier.precompile` compiles
+        ahead of the first dispatch."""
+        v, _, _ = self._verifier(kind, tuple(shape))
+        return v._run, v._sharding
+
     # -- dispatch -----------------------------------------------------------
 
     def _pre_dispatch(self, kind: str, chips, rows) -> None:
@@ -526,41 +534,36 @@ def auto_mesh(observer: PipelineMetrics | None = None):
     (DCN × ICI) mesh. Mesh policy gates first: a CPU fleet emulation
     still needs LODESTAR_TPU_MESH=force.
 
-    Returns a BlsMeshDispatcher or None. Never raises: a verifier must
-    construct even when jax device enumeration is broken (the supervisor
-    owns that failure)."""
+    Returns a BlsMeshDispatcher or None. A multi-device host whose mesh
+    cannot be built raises: serving unsharded there would hide lost
+    chips behind a node that looks healthy."""
     from ..utils.env import env_str
 
     mode = (env_str("LODESTAR_TPU_MESH") or "auto").strip().lower()
     if mode in ("0", "off", "false", "none"):
         return None
-    try:
-        from .fleet import FleetTopology
+    from .fleet import FleetTopology
 
-        topo = FleetTopology.from_env()
-        if topo.active:
-            # must precede jax.devices(): the distributed runtime is what
-            # makes remote hosts' devices visible in the global census
-            topo.ensure_initialized()
-        import jax
+    topo = FleetTopology.from_env()
+    if topo.active:
+        # must precede jax.devices(): the distributed runtime is what
+        # makes remote hosts' devices visible in the global census
+        topo.ensure_initialized()
+    import jax
 
-        devices = jax.devices()
-        if len(devices) < 2:
-            return None
-        if mode not in ("1", "on", "force") and devices[0].platform == "cpu":
-            return None
-        hosts = topo.group_devices(devices) if topo.active else None
-        dispatcher = BlsMeshDispatcher(devices, observer=observer,
-                                       hosts=hosts)
-        if not dispatcher.enabled:
-            return None
-        logger.info(
-            "mesh serving enabled: %d %s device(s), serving size %d "
-            "across %d host(s)",
-            len(devices), devices[0].platform, dispatcher.size,
-            dispatcher.hosts_serving,
-        )
-        return dispatcher
-    except Exception as e:  # pragma: no cover - env-dependent
-        logger.warning("mesh auto-detect failed (%s); serving unsharded", e)
+    devices = jax.devices()
+    if len(devices) < 2:
         return None
+    if mode not in ("1", "on", "force") and devices[0].platform == "cpu":
+        return None
+    hosts = topo.group_devices(devices) if topo.active else None
+    dispatcher = BlsMeshDispatcher(devices, observer=observer, hosts=hosts)
+    if not dispatcher.enabled:
+        return None
+    logger.info(
+        "mesh serving enabled: %d %s device(s), serving size %d "
+        "across %d host(s)",
+        len(devices), devices[0].platform, dispatcher.size,
+        dispatcher.hosts_serving,
+    )
+    return dispatcher

@@ -43,18 +43,11 @@ from ..ops.g2_decompress import (
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """`jax.shard_map` across jax versions: the top-level API (with
-    `check_vma`) landed after 0.4.x, where it lives in
-    `jax.experimental.shard_map` and the kwarg is `check_rep`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """`jax.shard_map` with replication checking off: the kernels gather
+    partials with explicit collectives and return replicated verdicts."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
 
 
@@ -66,19 +59,11 @@ def _axes(mesh_axis) -> tuple:
     return (mesh_axis,) if isinstance(mesh_axis, str) else tuple(mesh_axis)
 
 
-def _one_axis_size(name):
-    # lax.axis_size is newer-jax; psum(1, axis) is the 0.4.x idiom (static)
-    return (
-        lax.axis_size(name) if hasattr(lax, "axis_size")
-        else lax.psum(1, name)
-    )
-
-
 def _mesh_size(mesh_axis):
     """Total chip count across all (1 or 2) mesh axes."""
     n = 1
     for name in _axes(mesh_axis):
-        n = n * _one_axis_size(name)
+        n = n * lax.axis_size(name)
     return n
 
 
@@ -89,7 +74,7 @@ def _mesh_index(mesh_axis):
     chip 0) is the root-tail owner."""
     idx = 0
     for name in _axes(mesh_axis):
-        idx = idx * _one_axis_size(name) + lax.axis_index(name)
+        idx = idx * lax.axis_size(name) + lax.axis_index(name)
     return idx
 
 

@@ -43,7 +43,7 @@ from ..bls.hash_to_curve import hash_to_g2
 from ..observability.stages import default_pipeline
 from ..observability.trace import named_scope
 from ..testing import faults as _faults
-from ..ops import fp, fp2, fp12, msm, pallas_tower
+from ..ops import fp, fp2, fp12, msm
 from ..ops.g2_decompress import decompress as _g2_decompress, planes_in_subgroup as _planes_in_subgroup
 from ..ops.io_host import g1_affine_to_limbs, g2_affine_to_limbs
 from ..ops.pairing import (
@@ -454,12 +454,6 @@ def individual_verify_kernel(pk_x, pk_y, msg_x, msg_y, sig_x, sig_y, valid):
     2N Miller loops and N final exponentiations run batched. Returns
     (N,) bool; padding lanes report False.
     """
-    if pallas_tower.pairing_enabled():
-        # whole pairing (Miller loop + batched final exp) fused per tile in
-        # VMEM — no HBM spill of the Fp12 accumulator between the two halves
-        fe = pallas_tower.pairing_fused_pallas(
-            (pk_x, pk_y), (msg_x, msg_y), (sig_x, sig_y))
-        return fp12.is_one(fe) & valid
     prod = _individual_pairing_terms(pk_x, pk_y, msg_x, msg_y, sig_x, sig_y)
     # the (N,)-wide batched final exp is the per-set path's latency win:
     # ONE shared easy-part inversion chain instead of N (ISSUE 14)
@@ -522,14 +516,8 @@ def bisect_tree_kernel(pk_x, pk_y, msg_x, msg_y, sig_x, sig_y, r_bits, valid):
         fs = miller_loop_proj_pq((px, py, pz), (qx, qy, qz))
     f = fp12.mul(fs[:n], fs[n:])
     f = fp12.select(valid, f, fp12.one((n,)))
-    m = 1 << max(0, (n - 1).bit_length())
-    if m > n:
-        f = jnp.concatenate([f, fp12.one((m - n,))], 0)
     with named_scope("bls/product_tree"):
-        levels = [f]
-        while f.shape[0] > 1:
-            f = fp12.mul(f[0::2], f[1::2])
-            levels.append(f)
+        levels = fp12.product_levels(f)
     with named_scope("bls/final_exp_batch"):
         root_ok = fp12.is_one(final_exponentiation_one(levels[-1][0]))
     return root_ok, levels
@@ -552,27 +540,6 @@ def final_exp_batch_kernel(fs):
     the same `final_exponentiation_batch` code."""
     with named_scope("bls/final_exp_batch"):
         return fp12.is_one(final_exponentiation_batch(fs))
-
-
-def miller_pallas_kernel(pk_x, pk_y, msg_x, msg_y):
-    """Affine Miller loop forced onto the VMEM-resident Pallas tower
-    kernel (ops/pallas_tower.py) regardless of the dispatch knob — the
-    warmup/ledger compile unit for the LODESTAR_TPU_PALLAS_MILLER path
-    (production kernels route here implicitly via `pairing.miller_loop`
-    when the knob resolves on)."""
-    return pallas_tower.miller_loop_pallas((pk_x, pk_y), (msg_x, msg_y))
-
-
-def pairing_pallas_kernel(pk_x, pk_y, msg_x, msg_y, sig_x, sig_y, valid):
-    """Per-set verdicts forced through the VMEM-resident fused
-    full-pairing Pallas kernel (ops/pallas_tower.py) regardless of the
-    LODESTAR_TPU_PALLAS_PAIRING knob — the warmup/ledger compile unit
-    for the fused path (`individual_verify_kernel` routes here
-    implicitly when the knob resolves on). Verdict-identical to the XLA
-    `miller_loop` + `final_exponentiation_batch` route."""
-    fe = pallas_tower.pairing_fused_pallas(
-        (pk_x, pk_y), (msg_x, msg_y), (sig_x, sig_y))
-    return fp12.is_one(fe) & valid
 
 
 class SetArrays:
@@ -697,6 +664,30 @@ def _rand_pairs(shape: tuple[int, ...], rng=None):
     return a_bits, b_bits
 
 
+# XLA:TPU compiles of the whole verify kernels recurse deeply: a thread's
+# default 8 MiB stack has overflowed (SIGSEGV) compiling them, so the
+# threads that compile concurrently get a larger one
+COMPILE_THREAD_STACK = 512 << 20
+
+
+def run_concurrently(calls, workers: int | None = None) -> list:
+    """Run zero-arg callables on `workers` threads (default: one each),
+    each thread with a COMPILE_THREAD_STACK stack; results in order,
+    the first exception re-raised. For compiles, which release the GIL."""
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    if not calls:
+        return []
+    prev = threading.stack_size(COMPILE_THREAD_STACK)
+    try:
+        with ThreadPoolExecutor(workers or len(calls)) as ex:
+            futs = [ex.submit(c) for c in calls]
+    finally:
+        threading.stack_size(prev)
+    return [f.result() for f in futs]
+
+
 class BatchVerifier:
     """Shape-bucketed jitted kernels. One compile per bucket size, cached.
 
@@ -745,20 +736,12 @@ class BatchVerifier:
         )
         self._bisect_tree = _wrap(jax.jit(bisect_tree_kernel), "bisect_tree")
         self._bisect_probe = _wrap(jax.jit(bisect_probe_kernel), "bisect_probe")
-        # ISSUE 14 compile units: the standalone shared-inversion batched
-        # final exp and the Pallas Miller tower — wrapped so their first
-        # dispatches are timed, cache-classified and visible at
-        # /debug/compiles like the 9 fused kernels above
+        # ISSUE 14 compile unit: the standalone shared-inversion batched
+        # final exp — wrapped so its first dispatch is timed,
+        # cache-classified and visible at /debug/compiles like the 9
+        # fused kernels above
         self._final_exp_batch = _wrap(
             jax.jit(final_exp_batch_kernel), "final_exp_batch"
-        )
-        self._miller_pallas = _wrap(
-            jax.jit(miller_pallas_kernel), "miller_pallas"
-        )
-        # ISSUE 18 compile unit: the fused full-pairing Pallas kernel
-        # (Miller loop + batched final exp, VMEM-resident per tile)
-        self._pairing_pallas = _wrap(
-            jax.jit(pairing_pallas_kernel), "pairing_pallas"
         )
 
     def bucket_for(self, n: int) -> int:
@@ -831,23 +814,6 @@ class BatchVerifier:
         """(N,) stacked Fp12 products → (N,) bool through the standalone
         shared-inversion batched final-exp compile unit."""
         return self._final_exp_batch(fs)
-
-    def miller_pallas(self, p_aff, q_aff):
-        """VMEM-resident Pallas Miller tower on affine (P, Q) — warmup
-        rung and /debug/compiles entry; production dispatch reaches the
-        same kernel via `ops.pairing.miller_loop` when
-        LODESTAR_TPU_PALLAS_MILLER resolves on."""
-        return self._miller_pallas(p_aff[0], p_aff[1], q_aff[0], q_aff[1])
-
-    def pairing_pallas(self, arrs: SetArrays):
-        """Per-set verdicts through the fused full-pairing Pallas kernel
-        regardless of the LODESTAR_TPU_PALLAS_PAIRING knob — warmup rung
-        and /debug/compiles entry; production dispatch reaches the same
-        kernel via `individual_verify_kernel` when the knob resolves on."""
-        return self._pairing_pallas(
-            arrs.pk_x, arrs.pk_y, arrs.msg_x, arrs.msg_y,
-            arrs.sig_x, arrs.sig_y, arrs.valid,
-        )
 
 
 class TpuBlsVerifier:
@@ -946,6 +912,85 @@ class TpuBlsVerifier:
             self._epoch_table = EpochPubkeyTable(observer=self.observer)
         else:
             self._epoch_table = None
+
+    # -- ahead-of-time compilation -------------------------------------------
+
+    PRECOMPILE_KINDS = ("grouped", "pk_grouped", "per_set", "bisect")
+
+    def kernel_shapes(self, kinds=PRECOMPILE_KINDS, sharding=None):
+        """(ledger-wrapped jit entry, argument shapes) for every kernel
+        this verifier's shapes can dispatch on the paths in `kinds`:
+        grouped (per grouped config), pk_grouped (per pk-grouped config),
+        per_set (per bucket) and bisect (the per-set verdict tree per
+        bucket, plus its probe). A shape the serving mesh takes is its
+        sharded twin with inputs placed on the mesh, as dispatch routes
+        it; the rest are single-device kernels on `sharding` (None = the
+        default device). Shapes are `jax.ShapeDtypeStruct`s with the
+        dtypes the marshallers produce."""
+        k = self.kernels
+        raw = self._device_decompress
+        jobs = []
+
+        def add(fn, shapes, mesh_kind=None, mesh_key=None):
+            on = sharding
+            if mesh_kind is not None and self._mesh_shardable(mesh_key[0]):
+                fn, on = self._mesh.compile_target(mesh_kind, mesh_key)
+            jobs.append((fn, tuple(
+                jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+                for shape, dtype in shapes
+            )))
+
+        i32, u8, b = jnp.int32, jnp.uint8, jnp.bool_
+        for kind, configs, fn, fn_raw in (
+            ("grouped", k.grouped_configs, k._grouped, k._grouped_raw),
+            ("pk_grouped", k.pk_grouped_configs, k._pk_grouped,
+             k._pk_grouped_raw),
+        ):
+            if kind not in kinds:
+                continue
+            for r, l in configs:
+                if kind == "grouped":
+                    pk, msg = ((r, l, N_LIMBS), i32), ((r, 2, N_LIMBS), i32)
+                else:
+                    pk, msg = ((r, N_LIMBS), i32), ((r, l, 2, N_LIMBS), i32)
+                bits, valid = ((r, l, HALF_BITS), i32), ((r, l), b)
+                sig = ([((r, l, 96), u8)] if raw
+                       else [((r, l, 2, N_LIMBS), i32)] * 2)
+                add(fn_raw if raw else fn,
+                    [pk, pk, msg, msg, *sig, bits, bits, valid],
+                    kind + ("_raw" if raw else ""), (r, l))
+        for n in k.buckets:
+            pk, g2_ = ((n, N_LIMBS), i32), ((n, 2, N_LIMBS), i32)
+            r_bits, valid = ((n, R_BITS), i32), ((n,), b)
+            if "per_set" in kinds:
+                sig = [((n, 96), u8)] if raw else [g2_, g2_]
+                add(k._batch_raw if raw else k._batch,
+                    [pk, pk, g2_, g2_, *sig, r_bits, valid])
+            if "bisect" in kinds:
+                # the sharded tree takes power-of-two batches only
+                pow2 = not n & (n - 1)
+                add(k._bisect_tree,
+                    [pk, pk, g2_, g2_, g2_, g2_, r_bits, valid],
+                    "bisect" if pow2 else None, (n,))
+        if "bisect" in kinds:
+            add(k._bisect_probe, [((PROBE_LANES, 2, 3, 2, N_LIMBS), i32)])
+        return jobs
+
+    def precompile(self) -> list[str]:
+        """Compile every kernel this verifier can dispatch
+        (`kernel_shapes()`) all at once on concurrent threads (XLA
+        compiles release the GIL), before the first batch: each is
+        minutes of compile for a TPU, and a first dispatch that compiles
+        would blow the supervisor's per-dispatch deadline. Returns the
+        compiled kernel names; each compile is a compile-ledger event."""
+        from ..observability.compile_ledger import ledger
+
+        jobs = self.kernel_shapes()
+        led = ledger()
+        run_concurrently([
+            partial(led.compile_ahead, fn, *shapes) for fn, shapes in jobs
+        ])
+        return [fn.__compile_ledger_kernel__ for fn, _ in jobs]
 
     # -- mesh passthroughs (supervisor failure policy) ----------------------
 

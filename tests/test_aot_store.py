@@ -446,3 +446,26 @@ def test_cold_restart_round_trip_serving_ready_slo(tmp_path):
     assert doc["serving_ready_s"] <= 10.0, (
         f"serving-ready {doc['serving_ready_s']:.1f}s blows the 10 s SLO"
     )
+
+
+def test_store_and_cache_follow_jax_compilation_cache_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, holds the compile cache AND
+    the AOT store, and no code sets another directory; unset, both fall
+    back to the fixed repo-local directories."""
+    import jax
+
+    from lodestar_tpu.utils import jax_env
+
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert jax_env.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        assert aot_store.default_store_dir() == str(tmp_path / "aot_store")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo_cache = jax_env.enable_compile_cache()
+        assert repo_cache.endswith(os.sep + ".jax_cache")
+        assert aot_store.default_store_dir().endswith(os.sep + ".aot_store")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

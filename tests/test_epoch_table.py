@@ -3,8 +3,7 @@ eviction / device-OOM-fallback contract, the `_pk_rows` table consult,
 and the lane dispatcher's H(msg) dedup pre-warm.
 
 Everything host-side — table bookkeeping and marshal-path lookups run
-without any kernel dispatch, so the file stays in the fast tier. The
-fused-pairing differential twins live in tests/test_pallas_tower.py.
+without any kernel dispatch, so the file stays in the fast tier.
 """
 
 import threading

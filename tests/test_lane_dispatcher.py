@@ -321,3 +321,34 @@ def test_overlap_fraction_gauge_exported_before_first_flood():
         assert "lodestar_bls_lane_overlap_fraction 0" in p.registry.expose()
     finally:
         d.close()
+
+
+def test_submit_coalesces_a_burst_into_one_batch_without_threads():
+    """`submit` queues without blocking: one caller holding a gossip
+    burst submits every request, and the size trigger dispatches them as
+    ONE coalesced batch whose per-request verdicts resolve afterwards."""
+    inner = _GateVerifier()
+    inner.gate.set()
+    d = _dispatcher(inner, max_sigs=64, max_coalesce=64)
+    try:
+        pending = [
+            d.submit([f"set{i}"], lane="attestation") for i in range(64)
+        ]
+        assert [r() for r in pending] == [True] * 64
+        assert len(inner.calls) == 1 and len(inner.calls[0]) == 64
+    finally:
+        d.close()
+
+
+def test_submit_raises_shed_at_admission():
+    """A shed request raises at `submit`, before any resolver exists;
+    queued ones resolve with the typed rejection when the dispatcher
+    closes under them."""
+    d = _dispatcher(lane_caps={"attestation": 2})
+    queued = d.submit(["a", "b"], lane="attestation")
+    with pytest.raises(BlsShedError):
+        d.submit(["c"], lane="attestation")
+    assert d.submit([], lane="attestation")() is False
+    d.close()
+    with pytest.raises(BlsShedError):
+        queued()

@@ -110,6 +110,51 @@ def test_dispatch_grouped_routes_and_counts():
     assert snap["chip_dispatches"] == {"0": 2, "1": 2, "2": 2, "3": 2}
 
 
+class _RunStub(_StubVerifier):
+    """A sharded verifier whose jit entry (`_run`) and input sharding
+    are what `compile_target` hands to the precompile."""
+
+    def __init__(self, kind, devices, axis):
+        super().__init__(kind, devices, axis)
+        self._run = lambda *args: True
+        self._sharding = ("sharding", kind)
+
+    def submit(self, *args):
+        self.submits += 1
+        return self._run()
+
+
+@pytest.mark.parametrize("kind,key,dispatch", [
+    ("grouped_raw", (8, 64),
+     lambda d: d.dispatch_grouped_raw(_FakeGrouped(8, 64), None, None, None)),
+    ("pk_grouped_raw", (8, 64),
+     lambda d: d.dispatch_pk_grouped_raw(_FakeGrouped(8, 64), None, None,
+                                         None)),
+    ("bisect", (8,), lambda d: d.dispatch_bisect(_FakeArrs(8), None)),
+])
+def test_compile_target_is_the_verifier_its_dispatch_uses(kind, key,
+                                                          dispatch):
+    """What the precompile compiles is the very verifier (and ledger
+    key) the first dispatch of that shape then runs: no second build."""
+    calls = []
+
+    def factory(k, devices, axis):
+        calls.append(_RunStub(k, devices, axis))
+        return calls[-1]
+
+    d = BlsMeshDispatcher(
+        [f"dev{i}" for i in range(4)], observer=PipelineMetrics(),
+        verifier_factory=factory,
+    )
+    fn, sharding = d.compile_target(kind, key)
+    assert sharding == ("sharding", kind)
+    assert fn.__compile_ledger_kernel__ == f"sharded_{kind}"
+    assert fn.__compile_ledger_static_key__ == f"{key}@chips0,1,2,3"
+    assert dispatch(d)
+    assert len(calls) == 1 and calls[0].submits == 1
+    assert calls[0]._run is fn
+
+
 def test_dispatch_grouped_raw_routes_and_counts():
     """ISSUE 15: the zero-copy raw twins route through the same verifier
     cache and chip accounting as the limb kernels, under their own
@@ -234,6 +279,20 @@ def test_auto_mesh_cpu_devices_need_force(monkeypatch):
     monkeypatch.setenv("LODESTAR_TPU_MESH", "force")
     d = auto_mesh(PipelineMetrics())
     assert d is not None and d.enabled and d.size == 8
+
+
+def test_auto_mesh_that_cannot_be_built_raises(monkeypatch):
+    """A multi-device host whose mesh fails to build must not quietly
+    serve unsharded (lost chips behind a healthy-looking node)."""
+    import lodestar_tpu.parallel.mesh as mesh_mod
+
+    def broken(*a, **kw):
+        raise RuntimeError("mesh construction failed")
+
+    monkeypatch.setenv("LODESTAR_TPU_MESH", "force")
+    monkeypatch.setattr(mesh_mod, "BlsMeshDispatcher", broken)
+    with pytest.raises(RuntimeError, match="mesh construction failed"):
+        auto_mesh(PipelineMetrics())
 
 
 # --- supervisor wiring -------------------------------------------------------

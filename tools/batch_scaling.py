@@ -10,10 +10,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 
 import jax
 
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache"),
-)
+from lodestar_tpu.utils.jax_env import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
 
 
 def main():

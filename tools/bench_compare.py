@@ -83,10 +83,6 @@ REQUIRED_GATED_KEYS = (
     # the facade with a mesh attached, signature bytes decompressed
     # on-device per chip — the e2e acceptance row for mesh ingest
     "e2e_mesh_raw_sets_per_sec",
-    # ISSUE 18: the fused full-pairing rate (emitted only where the
-    # Pallas pairing knob resolves on — TPU deploys; absent history
-    # skips the gate, so CPU-only rounds stay green)
-    "device_sets_per_sec_fused_pairing",
     # ISSUE 18: the epoch-warm attestation-lane host-marshal rate (the
     # epoch table + H(msg) dedup win; parity-gated in its phase)
     "attestation_epoch_warm_sets_per_sec",

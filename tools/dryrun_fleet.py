@@ -100,11 +100,9 @@ def _mesh_parity() -> dict:
     import jax
     import numpy as np
 
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                     ".jax_cache"),
-    )
+    from lodestar_tpu.utils.jax_env import enable_compile_cache
+
+    enable_compile_cache()
 
     from lodestar_tpu.bls import api as bls
     from lodestar_tpu.parallel.fleet import FleetRouter

@@ -19,10 +19,9 @@ elif mode == "mxu2":
 
 import jax
 
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache"),
-)
+from lodestar_tpu.utils.jax_env import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
 
 from __graft_entry__ import _example_arrays  # noqa: E402
 from lodestar_tpu.parallel.verifier import batch_verify_kernel  # noqa: E402

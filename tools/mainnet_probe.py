@@ -49,12 +49,9 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
-import jax
+from lodestar_tpu.utils.jax_env import enable_compile_cache  # noqa: E402
 
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache"),
-)
+enable_compile_cache()
 
 
 N_VALIDATORS = int(os.environ.get("MAINNET_PROBE_VALIDATORS", "1000000"))

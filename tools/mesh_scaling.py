@@ -33,10 +33,9 @@ force_platform("cpu", N_MAX)
 
 import jax
 
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache"),
-)
+from lodestar_tpu.utils.jax_env import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
 
 import numpy as np
 from jax.sharding import Mesh

@@ -16,10 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache"),
-)
+from lodestar_tpu.utils.jax_env import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
 
 BATCH = int(os.environ.get("PROFILE_BATCH", "4096"))
 REPS = int(os.environ.get("PROFILE_REPS", "3"))

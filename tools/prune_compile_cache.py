@@ -35,8 +35,11 @@ import sys
 
 DEFAULT_LIMIT_GB = 2.0
 ENV_LIMIT = "LODESTAR_TPU_CACHE_LIMIT_GB"
-DEFAULT_CACHE_DIR = os.path.abspath(
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache")
+# the cache utils/jax_env.enable_compile_cache points JAX at
+DEFAULT_CACHE_DIR = os.environ.get("JAX_COMPILATION_CACHE_DIR") or (
+    os.path.abspath(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache"
+    ))
 )
 
 
@@ -188,7 +191,8 @@ def _observe(result: dict) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--cache-dir", default=DEFAULT_CACHE_DIR,
-                    help="compile-cache directory (default: repo .jax_cache)")
+                    help="compile-cache directory (default: "
+                    "JAX_COMPILATION_CACHE_DIR, else repo .jax_cache)")
     ap.add_argument("--limit-gb", type=float, default=None,
                     help=f"size bound in GiB (default: ${ENV_LIMIT} or "
                          f"{DEFAULT_LIMIT_GB})")

@@ -25,11 +25,8 @@ steady-state: per-set buckets (4, 16, 64, 128) + grouped configs
 (16x8, 64x64) + the pk-grouped config (128x32 — the adversarial
 unique-root flood defense routes here) + the bisection-verdict tree
 kernel per bucket and its fixed-shape probe kernel (the per-set verdict
-path, round 6) + the standalone batched final exp and — when
-LODESTAR_TPU_PALLAS_MILLER / LODESTAR_TPU_PALLAS_PAIRING resolve on —
-the Pallas Miller tower (ISSUE 14) and the fused full-pairing kernel
-(ISSUE 18) + the epoch-table gather kernel + the bench shapes when
---bench is given. Device
+path, round 6) + the standalone batched final exp + the epoch-table
+gather kernel + the bench shapes when --bench is given. Device
 decompression is DEFAULT-ON (round 6), so the *_raw kernel variants —
 on-chip signature decode + subgroup checks — are warmed for the same
 shapes by default; LODESTAR_TPU_DEVICE_DECOMPRESS=0 (or
@@ -38,8 +35,9 @@ marshal. Reference analog: the reference avoids this class of problem
 by having no compile step at all (blst is AOT); on TPU the restart
 story is "run warmup.py once per binary/kernel revision"
 (docs/architecture.md §compile-cache). The cache location honors
-LODESTAR_TPU_COMPILE_CACHE (utils/jax_env.enable_compile_cache) like
-node.py and bench.py.
+JAX_COMPILATION_CACHE_DIR (utils/jax_env.enable_compile_cache) like
+node.py and bench.py: that directory when set, else the repo-local
+`.jax_cache`.
 """
 
 from __future__ import annotations
@@ -51,9 +49,9 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
-CACHE_DIR = os.path.abspath(
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache")
-)
+from lodestar_tpu.utils.jax_env import cache_dir  # noqa: E402
+
+CACHE_DIR = cache_dir()
 
 
 def prune_cache(limit_gb: float | None = None) -> None:
@@ -80,7 +78,7 @@ def warm_production(include_bench: bool, device_decompress: bool = True) -> None
     from lodestar_tpu.observability.compile_ledger import ledger, timeline
     from lodestar_tpu.utils.jax_env import enable_compile_cache
 
-    enable_compile_cache(CACHE_DIR)
+    enable_compile_cache()
     timeline().mark("warmup_start")
     # objectives loaded before any kernel warms: the cold-start table
     # gains an `slo_ready` column, and burn state covers the whole
@@ -156,43 +154,6 @@ def warm_production(include_bench: bool, device_decompress: bool = True) -> None
     print(f"final exp batch x{PROBE_LANES}: {time.monotonic() - t0:.1f}s",
           flush=True)
     timeline().mark("rung_final_exp_batch")
-    # the VMEM-resident Pallas Miller tower: warmed only when the
-    # LODESTAR_TPU_PALLAS_MILLER knob resolves on (TPU deploys; the CPU
-    # interpreter path is a differential-test vehicle, not a serving
-    # shape worth a warmup rung)
-    from lodestar_tpu.ops import pallas_tower
-
-    if pallas_tower.enabled():
-        arrs = SetArrays(buckets[0])
-        (arrs.pk_x, arrs.pk_y, arrs.msg_x, arrs.msg_y,
-         arrs.sig_x, arrs.sig_y, _r_bits, arrs.valid) = _example_arrays(
-            buckets[0]
-        )
-        t0 = time.monotonic()
-        out = bv.miller_pallas(
-            (arrs.pk_x, arrs.pk_y), (arrs.msg_x, arrs.msg_y)
-        )
-        jax.block_until_ready(out)
-        print(f"miller pallas x{buckets[0]}: {time.monotonic() - t0:.1f}s",
-              flush=True)
-        timeline().mark("rung_miller_pallas")
-    # the fused full-pairing kernel (ISSUE 18): same gating logic — on
-    # TPU deploys the per-set verdict path routes here, so its compile
-    # belongs in the ladder; the CPU interpreter path stays a
-    # differential-test vehicle
-    if pallas_tower.pairing_enabled():
-        arrs = SetArrays(buckets[0])
-        (arrs.pk_x, arrs.pk_y, arrs.msg_x, arrs.msg_y,
-         arrs.sig_x, arrs.sig_y, _r_bits, arrs.valid) = _example_arrays(
-            buckets[0]
-        )
-        arrs.n = buckets[0]
-        t0 = time.monotonic()
-        out = bv.pairing_pallas(arrs)
-        jax.block_until_ready(out)
-        print(f"pairing pallas x{buckets[0]}: {time.monotonic() - t0:.1f}s",
-              flush=True)
-        timeline().mark("rung_pairing_pallas")
     # the epoch-table gather kernel (ISSUE 18): one tiny compile that
     # otherwise lands on the first post-restart epoch transition
     from lodestar_tpu.parallel.epoch_table import ROW_WIDTH, EpochPubkeyTable
@@ -328,8 +289,9 @@ def warm_production(include_bench: bool, device_decompress: bool = True) -> None
         print(f"warmup: aot store {aot['store']}: {aot.get('counts', {})} "
               f"({aot.get('loaded_executables', 0)} executable(s) "
               f"in memory)", flush=True)
-    ledger().write_artifact(os.path.join(CACHE_DIR, "..",
-                                         "compile_ledger.json"))
+    ledger().write_artifact(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "..", "compile_ledger.json"
+    ))
 
 
 def warm_dryrun(n: int) -> None:
