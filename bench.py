@@ -1097,12 +1097,6 @@ def main() -> None:
     with em.phase("flood", deadline_s=deadline) as ph:
         ph.update(_bench_flood(pipeline))
 
-    _log("bench: stage-profile phase...")
-    with em.phase("stage_profile", deadline_s=deadline) as ph:
-        from lodestar_tpu.observability.stage_profile import profile_stages
-
-        ph.update(profile_stages(pipeline, batch=256))
-
     with em.phase("hasher", deadline_s=deadline) as ph:
         ph.update(_bench_hasher())
 

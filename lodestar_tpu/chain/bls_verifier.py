@@ -166,12 +166,12 @@ class DeviceBlsVerifier:
         self._profile_dir = env_str("LODESTAR_TPU_PROFILE")
         self._last_fallback_log = float("-inf")
 
-    def _annotate(self, label: str):
+    def _annotate(self, name: str, **meta):
         from ..observability import trace
 
         if self._profile_dir and not trace.profiling_active():
             trace.start_profiling(self._profile_dir)
-        return trace.annotation(label)
+        return trace.annotation(name, **meta)
 
     def start_profiling(self, trace_dir: str | None = None):
         from ..observability import trace
@@ -256,7 +256,7 @@ class DeviceBlsVerifier:
             return False
         self._note_decompress_fallback(sets)
         # chunk oversized batches (reference chunkifyMaximizeChunkSize)
-        with self._annotate(f"bls_verify_batch/{len(sets)}"):
+        with self._annotate("device/verify_batch", sets=len(sets)):
             for i in range(0, len(sets), self.max_sets_per_job):
                 if not self._inner.verify_signature_sets(
                     sets[i : i + self.max_sets_per_job]
@@ -269,7 +269,7 @@ class DeviceBlsVerifier:
         self._note_decompress_fallback(sets)
         out: list[bool] = []
         step = self.max_sets_per_verdict_job
-        with self._annotate(f"bls_verify_individual/{len(sets)}"):
+        with self._annotate("device/verify_individual", sets=len(sets)):
             for i in range(0, len(sets), step):
                 out.extend(
                     self._inner.verify_signature_sets_individual(
@@ -340,9 +340,8 @@ class BufferedVerifier:
         if not buffer:
             return
         now = time.monotonic()
-        if self.prom is not None:
-            for _, _, enq in buffer:
-                self.prom.bls_buffer_wait_seconds.observe(now - enq)
+        for _, _, enq in buffer:
+            self.pipeline.lane_wait("buffer", now - enq)
         t0 = time.monotonic()
         try:
             per_request = _verify_merged(

@@ -611,7 +611,10 @@ class SupervisedBlsVerifier:
                 "oracle", reason, n_sets,
             )
         try:
-            with self._maybe_span("bls/cpu_fallback", reason=reason):
+            with self.observer.stage("cpu_tier", span="supervisor/cpu_tier",
+                                     reason=reason, sets=n_sets):
+                self._maybe_span_event("bls/cpu_fallback", reason=reason,
+                                       sets=n_sets)
                 return fn()
         except Exception:
             self.observer.both_tiers_failed()

@@ -122,10 +122,6 @@ def create_beacon_metrics(registry: MetricsRegistry | None = None):
     m.bls_buffer_depth = r.gauge(
         "lodestar_bls_verifier_buffer_sigs", "signature sets waiting in the batch buffer"
     )
-    m.bls_buffer_wait_seconds = r.histogram(
-        "lodestar_bls_verifier_buffer_wait_seconds",
-        "time a set waited in the buffer before dispatch",
-    )
     m.bls_job_sets = r.histogram(
         "lodestar_bls_verifier_sets_per_job", "signature sets per device dispatch"
     )

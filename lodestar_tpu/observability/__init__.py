@@ -4,17 +4,16 @@ The reference ships prom-client metrics + the `lodestar_bls_thread_pool`
 Grafana dashboard; this package is the device-pipeline equivalent,
 threaded through the verifier stack:
 
-- `stages` — stage timers (monotonic, `block_until_ready`-bounded),
-  planner-decision counters, cache hit counters, flush/queue gauges,
-  and the device-busy-fraction sampler, all backed by
-  `metrics.registry` families so they render on `/metrics`.
-- `trace` — JAX profiler integration: `TraceAnnotation` host scopes,
-  `named_scope` device-graph scopes (no-ops without jax), and the
-  start/stop profiling switch shared by the verifier and the
-  `/profiler/*` endpoints on the metrics server.
-- `stage_profile` — per-stage sub-kernel timing (the tools/
-  kernel_profile methodology as a library) feeding the same stage
-  histogram; used by bench for the stage-time breakdown.
+- `stages` — stage timers (monotonic, `block_until_ready`-bounded,
+  each also a span on a running profiler trace), planner-decision
+  counters, cache hit counters, flush/queue gauges, and the
+  device-busy-fraction sampler, all backed by `metrics.registry`
+  families so they render on `/metrics`.
+- `trace` — JAX profiler integration: `annotation`, the one way the
+  program writes a host span into a trace, `named_scope` device-graph
+  scopes (no-ops without jax), and the start/stop profiling switch
+  shared by the verifier and the `/profiler/*` endpoints on the metrics
+  server.
 - `bench_emit` — structured bench emitter: per-phase deadlines with
   graceful skip, atexit/SIGTERM JSON flush, so a benchmark run ALWAYS
   ends in one parseable JSON document (kills the `parsed: null`

@@ -7,7 +7,7 @@ rc=124. This module makes every compile a first-class, measured event:
 
 - `CompileLedger.wrap(fn, kernel)` wraps a jitted callable at the
   construction seam (`BatchVerifier.__init__`, the mesh dispatcher's
-  sharded-verifier cache, `stage_profile`). The FIRST call per
+  sharded-verifier cache). The FIRST call per
   (kernel, signature) is timed wall-clock — jax compiles synchronously
   on the first dispatch of a new shape, and execution is async, so the
   first-call wall time is dominated by trace+lower+compile. Every later
@@ -28,7 +28,9 @@ rc=124. This module makes every compile a first-class, measured event:
   event lands BEFORE the call, so a wedged compile is identifiable in a
   watchdog post-mortem as started-but-unfinished), serve
   `/debug/compiles`, and persist as `compile_ledger.json` per
-  bench/warmup run.
+  bench/warmup run. The compile is also a span `compile/<kernel>` on a
+  running profiler trace, and an AOT store lookup a span
+  `compile/aot_lookup` (stats `kernel`, `key`, and `hit` when it loaded).
 
 `StartupTimeline` is the getting-to-serving half: `mark(phase)` records
 seconds since PROCESS start (anchored via /proc/self/stat field 22 so
@@ -49,7 +51,7 @@ import threading
 import time
 import weakref
 
-from . import flight_recorder
+from . import flight_recorder, trace
 
 __all__ = [
     "CompileLedger",
@@ -230,7 +232,8 @@ class CompileLedger:
         # post-mortem as started-but-unfinished
         flight_recorder.record("compile_start", kernel=kernel, key=key)
         t0 = time.monotonic()
-        out = compile_fn()
+        with trace.annotation(f"compile/{kernel}", key=key):
+            out = compile_fn()
         duration_s = time.monotonic() - t0
         if cache_dir is None:
             cache = "off"
@@ -305,7 +308,10 @@ class CompileLedger:
             return None
         t0 = time.monotonic()
         try:
-            exec_ = st.load(kernel, key)
+            with trace.annotation("compile/aot_lookup", kernel=kernel,
+                                  key=key) as span:
+                exec_ = st.load(kernel, key)
+                span.set_metadata(hit=1)
         except aot_store.AotMiss:
             self.note_aot(kernel, key, "miss")
             return None

@@ -14,10 +14,10 @@ in executor threads (explicit `context()` / `attach()` handoff, because
 Finished traces go to a bounded ring buffer; the metrics server's
 `/debug/traces` endpoint serves them as JSON, filterable by slot/root.
 The structured logger injects the current trace-id into every record
-(`utils/logger._TraceContextFilter`), and when the process-wide XLA
-profiler switch (`observability.trace`) is active, each span also opens
-a `jax.profiler.TraceAnnotation` — lifecycle spans then appear on the
-same timeline as PR 1's device stage scopes.
+(`utils/logger._TraceContextFilter`), and each span also writes
+`lifecycle/<name>` into a running profiler trace (`trace.annotation`),
+however that trace was started — lifecycle spans then appear on the same
+timeline as the verifier's stage spans and the device's ops.
 
 Zero-cost when disabled: `span()`/`trace()` return one shared no-op
 singleton (no allocation, no clock reads, no ring writes). Disable with
@@ -140,10 +140,8 @@ class Span:
         if self._root is self:
             self.t0_wall = time.time()
         self._token = _current.set(self)
-        if _xla_trace.profiling_active():
-            # link onto the XLA timeline next to PR 1's device stage scopes
-            self._annotation = _xla_trace.annotation(f"lifecycle/{self.name}")
-            self._annotation.__enter__()
+        self._annotation = _xla_trace.annotation(f"lifecycle/{self.name}")
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:

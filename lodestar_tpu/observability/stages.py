@@ -1,23 +1,26 @@
 """Stage timers + planner counters + queue gauges for the BLS pipeline.
 
-The stage catalogue follows the verification dataflow (docs/observability.md):
+The stage catalogue follows the verification dataflow (docs/observability.md).
+Every stage is timed on the host's monotonic clock into
+`lodestar_bls_pipeline_stage_seconds{stage}` and, from the same context
+manager, written as a span into a running profiler trace
+(`trace.annotation`, on the device trace's clock):
 
-host stages (timed inline, monotonic clock):
     marshal        wire bytes -> limb arrays (C tier decompress/subgroup)
     hash_to_curve  H(m) for cache-missed signing roots (C tier)
     rand           random-coefficient bit planes
     dispatch       host->XLA submit time (async; excludes device compute)
     device_wait    resolver block time (`block_until_ready`-bounded)
     bisect         bisection probe dispatches on a failed verdict tree
-                   (batched shared-easy-part final exps; device tag
-                   `bls/bisect` inside the probe kernel)
+    cpu_tier       the supervisor serving or auditing sets on the CPU
+                   tier, for every fallback reason, `negative_audit`
+                   included
 
-device stages (attributable two ways: `trace.named_scope` tags inside the
-fused kernel for XLA profiles, and `stage_profile.profile_stages` timing
-per-stage sub-kernels into the SAME histogram for the bench breakdown):
-    g2_decompress, scalar_mul, msm_planes, miller_loop, product_tree,
-    final_exp, final_exp_batch (batched shared-inversion final exp, device
-    tag `bls/final_exp_batch`)
+A stage's span is `stage/<name>`, but `cpu_tier`'s is
+`supervisor/cpu_tier`. Device time inside the fused kernels is
+not timed here: the kernels tag their ops with `trace.named_scope`
+(`bls/miller_loop`, `bls/final_exp_batch`, ...), and a device trace
+gives each scope's time (`benchmark/xspace.py`).
 
 All families live in a `metrics.registry.MetricsRegistry` so they render
 on `/metrics` next to the rest of the node's families. `default_pipeline()`
@@ -31,7 +34,7 @@ import threading
 import time
 
 from ..metrics.registry import MetricsRegistry
-from . import flight_recorder
+from . import flight_recorder, trace
 
 # label set of the build-info gauge (one constant-1 series whose labels
 # carry the runtime identity — utils/jax_env.runtime_info produces it)
@@ -47,13 +50,7 @@ STAGES = (
     "dispatch",
     "device_wait",
     "bisect",
-    "g2_decompress",
-    "scalar_mul",
-    "msm_planes",
-    "miller_loop",
-    "product_tree",
-    "final_exp",
-    "final_exp_batch",
+    "cpu_tier",
 )
 
 # planner decisions (parallel/verifier.verify_signature_sets_submit):
@@ -81,16 +78,20 @@ def _block_until_ready(x):
 
 
 class _StageTimer:
-    """Context manager: observes monotonic elapsed seconds into the stage
-    histogram. `bound(x)` registers a value to `block_until_ready` before
-    the clock stops, so async dispatch results are timed to completion."""
+    """Context manager: a span on the profiler's trace (`span`, default
+    `stage/<stage>`, with `meta` as its stats) around the stage, and its
+    monotonic elapsed seconds in the stage histogram. `bound(x)`
+    registers a value to `block_until_ready` before the clock stops, so
+    async dispatch results are timed to completion."""
 
-    __slots__ = ("_pipeline", "_stage", "_bound", "t0", "elapsed")
+    __slots__ = ("_pipeline", "_stage", "_bound", "_span", "t0", "elapsed")
 
-    def __init__(self, pipeline: "PipelineMetrics", stage: str):
+    def __init__(self, pipeline: "PipelineMetrics", stage: str,
+                 span: str | None = None, **meta):
         self._pipeline = pipeline
         self._stage = stage
         self._bound = None
+        self._span = trace.annotation(span or f"stage/{stage}", **meta)
         self.elapsed = 0.0
 
     def bound(self, x):
@@ -98,14 +99,19 @@ class _StageTimer:
         return x
 
     def __enter__(self):
+        self._span.__enter__()
         self.t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
-        if self._bound is not None:
-            _block_until_ready(self._bound)
-        self.elapsed = time.monotonic() - self.t0
-        self._pipeline.observe_stage(self._stage, self.elapsed)
+        try:
+            if self._bound is not None:
+                _block_until_ready(self._bound)
+        finally:
+            self.elapsed = time.monotonic() - self.t0
+            self._span.__exit__(*exc)
+            self._pipeline.stage_seconds.observe(self.elapsed,
+                                                 stage=self._stage)
         return False
 
 
@@ -318,6 +324,13 @@ class PipelineMetrics:
             "signature sets coalesced into one lane-dispatcher batch",
             buckets=_GROUP_SIZE_BUCKETS,
         )
+        self.lane_wait_seconds = r.histogram(
+            "lodestar_bls_verifier_buffer_wait_seconds",
+            "time a request waited in its lane (or the batch buffer) "
+            "before dispatch",
+            label_names=("lane",),
+            buckets=_STAGE_BUCKETS,
+        )
         self.lane_overlap_fraction = r.gauge(
             "lodestar_bls_lane_overlap_fraction",
             "fraction of dispatched batches whose host prep overlapped "
@@ -490,11 +503,11 @@ class PipelineMetrics:
 
     # -- stage timers -------------------------------------------------------
 
-    def stage(self, name: str) -> _StageTimer:
-        return _StageTimer(self, name)
-
-    def observe_stage(self, name: str, seconds: float) -> None:
-        self.stage_seconds.observe(seconds, stage=name)
+    def stage(self, name: str, span: str | None = None,
+              **meta) -> _StageTimer:
+        """Time stage `name` (one of STAGES) and span it on the trace as
+        `span` (default `stage/<name>`) with `meta` as the span's stats."""
+        return _StageTimer(self, name, span, **meta)
 
     # -- planner ------------------------------------------------------------
 
@@ -638,8 +651,18 @@ class PipelineMetrics:
         self.lane_sheds.inc(n_sets, lane=lane)
         flight_recorder.record("lane_shed", lane=lane, sets=n_sets)
 
-    def lane_coalesce(self, n_sets: int) -> None:
+    def lane_batch(self, lane: str, n_sets: int, reason: str):
+        """One lane-dispatcher batch: its size in the coalesced-sets
+        histogram, and the returned span `lane/batch` to hold around its
+        handling, pop to verdicts handed over."""
         self.lane_coalesced_sets.observe(n_sets)
+        return trace.annotation("lane/batch", lane=lane, sets=n_sets,
+                                reason=reason)
+
+    def lane_wait(self, lane: str, seconds: float) -> None:
+        """How long one request waited in `lane` before its batch was
+        dispatched."""
+        self.lane_wait_seconds.observe(seconds, lane=lane)
 
     def lane_overlap(self, overlapped: bool) -> None:
         with self._lane_lock:

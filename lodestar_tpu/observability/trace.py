@@ -3,8 +3,14 @@
 Two distinct scope kinds (both no-ops when jax is unavailable, so host
 code can annotate unconditionally):
 
-- `annotation(label)` — host-side `jax.profiler.TraceAnnotation`: marks a
-  wall-clock span on the profiler timeline (dispatch, marshal, resolve).
+- `annotation(name, **meta)` — the program's one way to write a host span
+  into a profiler trace: a `jax.profiler.TraceAnnotation`, on the device
+  trace's clock. It records nothing while no profiler runs, and opens
+  whether or not the trace was started through `start_profiling`, so a
+  trace started with `jax.profiler.start_trace` sees it too. Names are a
+  fixed set (`lane/...`, `stage/...`, `supervisor/...`, `compile/...`,
+  `device/...`, `lifecycle/...`); counts, lanes and reasons go in `meta`,
+  which the trace keeps as the event's stats.
 - `named_scope(label)` — trace-time `jax.named_scope`: tags the HLO ops
   emitted under it, so device stages (MSM planes, Miller loop, final
   exponentiation) are attributable inside ONE fused XLA dispatch where
@@ -26,13 +32,14 @@ _lock = threading.Lock()
 _active_dir: str | None = None
 
 
-def annotation(label: str):
-    """Host-side profiler span; nullcontext when jax is unavailable."""
+def annotation(name: str, **meta):
+    """Host-side profiler span `name` carrying `meta` as its stats;
+    nullcontext when jax is unavailable."""
     try:
         import jax
 
-        return jax.profiler.TraceAnnotation(label)
-    except Exception:
+        return jax.profiler.TraceAnnotation(name, **meta)
+    except ImportError:
         return contextlib.nullcontext()
 
 

@@ -69,7 +69,7 @@ class CurveOps:
 
     # -- group law (complete, branchless) -----------------------------------
     #
-    # LATENCY DISCIPLINE (round-2 profile, tools/kernel_profile.py): the
+    # LATENCY DISCIPLINE (round-2 profile of the ladders): the
     # scalar ladders are latency-bound — at 4096 lanes each Montgomery
     # multiply's sequential cost dominates, and wider stacked multiplies
     # are ~4× cheaper per lane. So every formula below evaluates its

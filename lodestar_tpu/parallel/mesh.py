@@ -313,7 +313,7 @@ class BlsMeshDispatcher:
             return NOT_SHARDED
         v, chips, rows = self._verifier("grouped", g.pk_x.shape[:2])
         self._pre_dispatch("grouped", chips, rows)
-        with trace.annotation(f"bls/mesh/grouped[{len(chips)}]"), \
+        with trace.annotation("device/mesh_grouped", chips=len(chips)), \
                 device_ledger.ledger().dispatch("grouped", chips):
             return self._submit_timed(
                 rows, lambda: v.submit(g, a_bits, b_bits)
@@ -327,7 +327,7 @@ class BlsMeshDispatcher:
             return NOT_SHARDED
         v, chips, rows = self._verifier("grouped_raw", g.pk_x.shape[:2])
         self._pre_dispatch("grouped_raw", chips, rows)
-        with trace.annotation(f"bls/mesh/grouped_raw[{len(chips)}]"), \
+        with trace.annotation("device/mesh_grouped_raw", chips=len(chips)), \
                 device_ledger.ledger().dispatch("grouped_raw", chips):
             return self._submit_timed(
                 rows, lambda: v.submit(g, sig_raw, a_bits, b_bits)
@@ -340,7 +340,7 @@ class BlsMeshDispatcher:
             return NOT_SHARDED
         v, chips, rows = self._verifier("pk_grouped", g.msg_x.shape[:2])
         self._pre_dispatch("pk_grouped", chips, rows)
-        with trace.annotation(f"bls/mesh/pk_grouped[{len(chips)}]"), \
+        with trace.annotation("device/mesh_pk_grouped", chips=len(chips)), \
                 device_ledger.ledger().dispatch("pk_grouped", chips):
             return self._submit_timed(
                 rows, lambda: v.submit(g, a_bits, b_bits)
@@ -354,7 +354,7 @@ class BlsMeshDispatcher:
             return NOT_SHARDED
         v, chips, rows = self._verifier("pk_grouped_raw", g.msg_x.shape[:2])
         self._pre_dispatch("pk_grouped_raw", chips, rows)
-        with trace.annotation(f"bls/mesh/pk_grouped_raw[{len(chips)}]"), \
+        with trace.annotation("device/mesh_pk_grouped_raw", chips=len(chips)), \
                 device_ledger.ledger().dispatch("pk_grouped_raw", chips):
             return self._submit_timed(
                 rows, lambda: v.submit(g, sig_raw, a_bits, b_bits)
@@ -370,7 +370,7 @@ class BlsMeshDispatcher:
             return NOT_SHARDED
         v, chips, rows = self._verifier("bisect", (lanes,))
         self._pre_dispatch("bisect", chips, rows)
-        with trace.annotation(f"bls/mesh/bisect[{len(chips)}]"), \
+        with trace.annotation("device/mesh_bisect", chips=len(chips)), \
                 device_ledger.ledger().dispatch("bisect", chips):
             return self._submit_timed(rows, lambda: v.submit(arrs, r_bits))
 

@@ -1580,11 +1580,10 @@ class TpuBlsVerifier:
         submit→resolve span (the device computes through the async gap,
         so resolver block time alone undercounts occupancy)."""
         t0 = time.monotonic()
-        verdict = bool(result)
-        now = time.monotonic()
-        self.observer.observe_stage("device_wait", now - t0)
+        with self.observer.stage("device_wait"):
+            verdict = bool(result)
         self.observer.device_busy_sample(
-            now - (t_submit if t_submit is not None else t0)
+            time.monotonic() - (t_submit if t_submit is not None else t0)
         )
         # flaky-verdict injection (testing.faults): True -> False only,
         # modeling corrupted device computation
