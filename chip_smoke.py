@@ -323,7 +323,8 @@ class Driver:
         t0 = time.perf_counter()
         self.stack = build_verifier_stack(pipeline, **shapes)
         log(f"stack: built in {time.perf_counter() - t0:.1f}s, kernels "
-            f"compiled ahead {self.stack.compiled}")
+            f"compiled ahead {self.stack.compiled}, planner prices (s) "
+            f"{self.stack.prices}")
         self.n_compiles = log_compiles()
         # time the supervisor's calls into the CPU tier (its audit of
         # device-rejected batches) from here on

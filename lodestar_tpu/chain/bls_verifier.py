@@ -149,12 +149,14 @@ class DeviceBlsVerifier:
         buckets: tuple[int, ...] = (4, 16, 64, MAX_SIGNATURE_SETS_PER_JOB),
         grouped_configs: tuple[tuple[int, int], ...] = ((16, 8), (64, 64)),
         observer=None,
+        pk_grouped_configs: tuple[tuple[int, int], ...] = ((128, 32),),
     ):
         from ..parallel.verifier import TpuBlsVerifier
         from ..utils.env import env_str
 
         self._inner = TpuBlsVerifier(
-            buckets=buckets, grouped_configs=grouped_configs, observer=observer
+            buckets=buckets, grouped_configs=grouped_configs,
+            pk_grouped_configs=pk_grouped_configs, observer=observer,
         )
         self.observer = self._inner.observer
         # a batch job may fill the largest grouped config (rows × lanes
@@ -190,6 +192,11 @@ class DeviceBlsVerifier:
         """Compile every kernel this tier can dispatch ahead of the
         first batch (parallel/verifier.TpuBlsVerifier.precompile)."""
         return self._inner.precompile()
+
+    def calibrate(self) -> dict:
+        """Price every kernel shape the planner can choose on this device
+        (parallel/verifier.TpuBlsVerifier.calibrate)."""
+        return self._inner.calibrate()
 
     # -- mesh passthroughs (supervisor failure policy; parallel/mesh) -------
 

@@ -19,7 +19,7 @@ builds the same stack to drive it on the chip.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 __all__ = [
     "BUCKETS",
@@ -52,6 +52,8 @@ class VerifierStack:
     cpu: object  # CpuBlsVerifier
     aot: dict  # compile ledger preload summary
     compiled: list  # kernel names compiled (or loaded) ahead
+    # the planner's calibrated seconds per (kernel, shape)
+    prices: dict = field(default_factory=dict)
 
     def close(self) -> None:
         self.dispatcher.close()
@@ -59,15 +61,18 @@ class VerifierStack:
 
 
 def build_verifier_stack(pipeline, prom=None, buckets=BUCKETS,
-                         grouped_configs=GROUPED_CONFIGS) -> VerifierStack:
+                         grouped_configs=GROUPED_CONFIGS,
+                         pk_grouped_configs=((128, 32),)) -> VerifierStack:
     """Build the stack on `pipeline` (the node's PipelineMetrics) and
     `prom` (its beacon metrics bundle, for the dispatcher's queue
     families), ready to serve: the persistent compile cache is on before
     any trace, every persisted AOT executable for this build is loaded,
-    and every kernel the device tier can dispatch is compiled — a first
+    every kernel the device tier can dispatch is compiled — a first
     dispatch that compiled would outlast the supervisor's deadline and
-    be served by the CPU tier. `buckets` and `grouped_configs` are the
-    kernel shapes; only tests shrink them."""
+    be served by the CPU tier — and then timed once, so the planner
+    prices each batch's plans in this device's seconds. `buckets`,
+    `grouped_configs` and `pk_grouped_configs` are the kernel shapes;
+    only tests shrink them."""
     from ..observability.compile_ledger import ledger
     from ..utils.jax_env import enable_compile_cache
     from .bls_verifier import CpuBlsVerifier, DeviceBlsVerifier
@@ -76,11 +81,14 @@ def build_verifier_stack(pipeline, prom=None, buckets=BUCKETS,
 
     enable_compile_cache()
     device = DeviceBlsVerifier(
-        buckets=buckets, grouped_configs=grouped_configs, observer=pipeline
+        buckets=buckets, grouped_configs=grouped_configs,
+        pk_grouped_configs=pk_grouped_configs, observer=pipeline,
     )
     aot = ledger().preload_aot()
     compiled = device.precompile()
+    prices = device.calibrate()
     cpu = CpuBlsVerifier()
     supervisor = SupervisedBlsVerifier(device, cpu, observer=pipeline)
     dispatcher = BlsLaneDispatcher(supervisor, prom=prom)
-    return VerifierStack(dispatcher, supervisor, device, cpu, aot, compiled)
+    return VerifierStack(dispatcher, supervisor, device, cpu, aot, compiled,
+                         prices)

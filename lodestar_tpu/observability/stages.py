@@ -57,10 +57,16 @@ STAGES = (
 #   root_grouped  whole batch on the root-grouped kernel
 #   pk_grouped    whole batch on the pubkey-grouped (dual) kernel
 #   split         shared-root part peeled off, remainder routed separately
-#                 (the parts also count under their own paths)
-#   per_set       flat per-set kernel (nothing grouped)
+#                 (the parts also count under their own paths; an
+#                 uncalibrated verifier only)
+#   row_overflow  the fullest root runs on one root-grouped call, the rest
+#                 flat or pk-grouped (priced; the parts count as above)
+#   priced_flat   a batch a grouped plan could take, sent flat because its
+#                 per-set calls cost less (priced; also counts as per_set)
+#   per_set       flat per-set kernel
 #   individual    per-set verdict retry path
-PLANNER_PATHS = ("root_grouped", "pk_grouped", "split", "per_set", "individual")
+PLANNER_PATHS = ("root_grouped", "pk_grouped", "split", "row_overflow",
+                 "priced_flat", "per_set", "individual")
 
 _STAGE_BUCKETS = (
     0.0001, 0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1, 5, 10, 60,
@@ -141,6 +147,12 @@ class PipelineMetrics:
             "lodestar_bls_verifier_planner_group_size",
             "sets per group row chosen by the planner",
             buckets=_GROUP_SIZE_BUCKETS,
+        )
+        self.planner_price = r.gauge(
+            "lodestar_bls_planner_price_seconds",
+            "calibrated device seconds of one call per kernel shape, the "
+            "planner's price list",
+            label_names=("kernel", "shape"),
         )
         self.cache_events = r.counter(
             "lodestar_bls_verifier_cache_events_total",
@@ -518,6 +530,14 @@ class PipelineMetrics:
             for size in group_sizes:
                 self.planner_group_size.observe(size)
         flight_recorder.record("dispatch", path=path, sets=n_sets)
+
+    def planner_prices(self, prices) -> None:
+        """Publish the planner's price list: {(kernel, shape): seconds},
+        shape a tuple (bucket lanes, or rows × lanes)."""
+        for (kernel, shape), seconds in prices.items():
+            self.planner_price.set(
+                seconds, kernel=kernel, shape="x".join(map(str, shape))
+            )
 
     def cache_event(self, cache: str, hit: bool, n: int = 1) -> None:
         if n:

@@ -629,6 +629,11 @@ class PkGroupedArrays:
         self.n = 0
 
 
+def _cheapest(plans):
+    """The first of the cheapest priced plans ((seconds, calls), ...)."""
+    return min(plans, key=lambda plan: plan[0])
+
+
 def _rand_bits(lanes: int, rng) -> np.ndarray:
     """(lanes, 64) nonzero random scalar bits, MSB first."""
     out = np.zeros((lanes, R_BITS), np.int32)
@@ -837,6 +842,7 @@ class TpuBlsVerifier:
         pk_grouped_configs: tuple[tuple[int, int], ...] = ((128, 32),),
         observer=None,
         mesh="auto",
+        prices=None,
     ):
         self.kernels = BatchVerifier(buckets, grouped_configs, pk_grouped_configs)
         # pipeline telemetry (observability.stages.PipelineMetrics): stage
@@ -912,6 +918,12 @@ class TpuBlsVerifier:
             self._epoch_table = EpochPubkeyTable(observer=self.observer)
         else:
             self._epoch_table = None
+        # the planner's price list, {(kernel, shape): seconds per call},
+        # measured on this device by `calibrate()`; until then (None)
+        # batches keep the unpriced order of `_unpriced_plan`
+        self._prices = None
+        if prices is not None:
+            self._set_prices(prices)
 
     # -- ahead-of-time compilation -------------------------------------------
 
@@ -991,6 +1003,59 @@ class TpuBlsVerifier:
             partial(led.compile_ahead, fn, *shapes) for fn, shapes in jobs
         ])
         return [fn.__compile_ledger_kernel__ for fn, _ in jobs]
+
+    # -- planner prices -------------------------------------------------------
+
+    def calibrate(self) -> dict:
+        """Measure the planner's prices on this device: the seconds of one
+        call of every kernel shape the planner can choose (the per-set
+        kernel at each bucket, the root- and pk-grouped kernels at each
+        config) on masked pad input (`valid` all False), through the
+        entry a real dispatch takes (raw or host-decompressed, sharded or
+        single-device), from dispatch to a ready verdict. A per-set call
+        is timed on its second run, past any one-time cost of the first;
+        a grouped call's seconds dwarf such costs. Run after
+        `precompile()`: from then on each batch is dispatched on its
+        cheapest plan (`_priced_plan`). Returns {(kernel, shape): s}."""
+        k = self.kernels
+        raw = self._device_decompress
+
+        def seconds(dispatch, *args) -> float:
+            t0 = time.monotonic()
+            bool(dispatch(*args))
+            return time.monotonic() - t0
+
+        prices = {}
+        for n in k.buckets:
+            args = (SetArrays(n), np.zeros((n, 96), np.uint8) if raw else None,
+                    _rand_bits(n, self._rng))
+            seconds(self._dispatch_per_set, *args)
+            prices[("per_set", (n,))] = seconds(self._dispatch_per_set, *args)
+        for kind, configs, arrays, dispatch in (
+            ("root_grouped", k.grouped_configs, GroupedArrays,
+             self._dispatch_grouped),
+            ("pk_grouped", k.pk_grouped_configs, PkGroupedArrays,
+             self._dispatch_pk_grouped),
+        ):
+            for r, l in configs:
+                sig_raw = np.zeros((r, l, 96), np.uint8) if raw else None
+                prices[(kind, (r, l))] = seconds(
+                    dispatch, arrays(r, l), sig_raw,
+                    *_rand_pairs((r, l), self._custom_rng),
+                )
+        self._set_prices(prices)
+        from ..utils.logger import get_logger
+
+        get_logger("bls-verifier").info(
+            "planner prices, seconds a call: %s",
+            ", ".join(f"{kind} {'x'.join(map(str, shape))} {s:.4f}"
+                      for (kind, shape), s in prices.items()),
+        )
+        return prices
+
+    def _set_prices(self, prices) -> None:
+        self._prices = dict(prices)
+        self.observer.planner_prices(self._prices)
 
     # -- mesh passthroughs (supervisor failure policy) ----------------------
 
@@ -1232,31 +1297,30 @@ class TpuBlsVerifier:
         return shared, unique
 
     @staticmethod
+    def _runs(keys, lane_cap) -> list[list[int]]:
+        """Item indices packed into per-key runs of ≤ lane_cap, in the
+        order each run opens: the rows of either grouping axis."""
+        runs: list[list[int]] = []
+        open_run: dict[bytes, list[int]] = {}
+        for idx, key in enumerate(keys):
+            run = open_run.get(key)
+            if run is not None and len(run) < lane_cap:
+                run.append(idx)
+            else:
+                run = open_run[key] = [idx]
+                runs.append(run)
+        return runs
+
+    @staticmethod
     def _plan_runs(keys, configs):
         """Shared run-packing for both grouping axes: pack items into
         per-key runs of ≤ lane_cap, ≤ rows_cap runs total; None when no
         config fits or fewer than half the items share keys."""
-        uniq = len(set(keys))
-        if uniq * 2 > len(keys):
+        if len(set(keys)) * 2 > len(keys):
             return None
         for rows_cap, lane_cap in configs:
-            if len(keys) > rows_cap * lane_cap:
-                continue
-            runs: list[list[int]] = []
-            open_run: dict[bytes, list[int]] = {}
-            fits = True
-            for idx, key in enumerate(keys):
-                run = open_run.get(key)
-                if run is not None and len(run) < lane_cap:
-                    run.append(idx)
-                else:
-                    run = [idx]
-                    runs.append(run)
-                    open_run[key] = run
-                    if len(runs) > rows_cap:
-                        fits = False
-                        break
-            if fits:
+            runs = TpuBlsVerifier._runs(keys, lane_cap)
+            if len(runs) <= rows_cap:
                 return rows_cap, lane_cap, runs
         return None
 
@@ -1375,68 +1439,6 @@ class TpuBlsVerifier:
         g.n = len(sets)
         return (g, sig_raw) if raw else g
 
-    def _submit_pk_grouped_mesh(self, sets, plan):
-        """Sharded pk-grouped dispatch (raw wire-byte signatures when
-        device decompression is on — see `_submit_grouped_mesh`)."""
-        from .mesh import NOT_SHARDED
-
-        if self._device_decompress:
-            with self.observer.stage("marshal"):
-                marshalled = self._marshal_pk_grouped(sets, plan, raw=True)
-            if marshalled is None:
-                return None
-            g, sig_raw = marshalled
-            with self.observer.stage("rand"):
-                a_bits, b_bits = _rand_pairs(g.valid.shape, self._custom_rng)
-            with self.observer.stage("dispatch"):
-                result = self._mesh.dispatch_pk_grouped_raw(
-                    g, sig_raw, a_bits, b_bits
-                )
-                if result is NOT_SHARDED:
-                    result = self.kernels.verify_pk_grouped_raw(
-                        g, sig_raw, a_bits, b_bits
-                    )
-            return result
-        with self.observer.stage("marshal"):
-            g = self._marshal_pk_grouped(sets, plan)
-        if g is None:
-            return None
-        with self.observer.stage("rand"):
-            a_bits, b_bits = _rand_pairs(g.valid.shape, self._custom_rng)
-        with self.observer.stage("dispatch"):
-            result = self._mesh.dispatch_pk_grouped(g, a_bits, b_bits)
-            if result is NOT_SHARDED:
-                result = self.kernels.verify_pk_grouped(g, a_bits, b_bits)
-        return result
-
-    def _submit_pk_grouped(self, sets, plan):
-        """Dispatch one pk-grouped batch; None marks an invalid set."""
-        self.observer.planner(
-            "pk_grouped", len(sets), group_sizes=[len(r) for r in plan[2]]
-        )
-        if self._mesh_shardable(plan[0]):
-            return self._submit_pk_grouped_mesh(sets, plan)
-        if self._device_decompress:
-            with self.observer.stage("marshal"):
-                marshalled = self._marshal_pk_grouped(sets, plan, raw=True)
-            if marshalled is None:
-                return None
-            g, sig_raw = marshalled
-            with self.observer.stage("rand"):
-                a_bits, b_bits = _rand_pairs(g.valid.shape, self._custom_rng)
-            with self.observer.stage("dispatch"):
-                return self.kernels.verify_pk_grouped_raw(
-                    g, sig_raw, a_bits, b_bits
-                )
-        with self.observer.stage("marshal"):
-            g = self._marshal_pk_grouped(sets, plan)
-        if g is None:
-            return None
-        with self.observer.stage("rand"):
-            a_bits, b_bits = _rand_pairs(g.valid.shape, self._custom_rng)
-        with self.observer.stage("dispatch"):
-            return self.kernels.verify_pk_grouped(g, a_bits, b_bits)
-
     def _marshal(self, sets, raw: bool = False):
         """Build padded device arrays; None if any set is invalid up front.
 
@@ -1529,50 +1531,123 @@ class TpuBlsVerifier:
         # armed via LODESTAR_TPU_FAULTS or /debug/faults — the supervisor
         # tier's failure policy is exercised against exactly this boundary
         _faults.on_device_dispatch(len(sets))
-        if sets and self._native_eligible(sets):
-            plan = self._plan_groups(sets)
+        if not (sets and self._native_eligible(sets)):
+            return self._submit_flat(sets)
+        path, parts = (
+            self._unpriced_plan(sets) if self._prices is None
+            else self._priced_plan(sets)
+        )
+        if path is not None:
+            # the parts also count under their own paths
+            self.observer.planner(path, len(sets))
+        t = time.monotonic()
+        verdicts = []
+        for kind, idx, plan in parts:
+            part = sets if idx is None else [sets[i] for i in idx]
+            if kind == "per_set":
+                verdicts.append(self._submit_flat(part))
+                continue
+            result = self._submit_grouped(part, plan, kind)
+            if result is None:
+                return lambda: False
+            verdicts.append(partial(self._resolve, result, t))
+        # every set is in exactly one part: the batch holds iff all do
+        return lambda: all(v() for v in verdicts)
+
+    # A plan is (path, parts): `path` the planner decision counted for
+    # the whole batch (None when its one part counts alone), each part
+    # (kernel, indices into the batch or None for all of it, the
+    # grouped kernel's (rows, lanes, runs) or None for per_set). A priced
+    # plan is ((seconds, calls), path, parts).
+
+    def _unpriced_plan(self, sets):
+        """The order of an uncalibrated verifier: the whole batch
+        root-grouped, else pk-grouped (pubkeys repeat in an adversarial
+        unique-root flood of bounded attacker keys), else the shared-root
+        sets peeled onto the grouped kernel and the singletons
+        pk-grouped or flat, else flat."""
+        plan = self._plan_groups(sets)
+        if plan is not None:
+            return None, [("root_grouped", None, plan)]
+        plan = self._plan_pk_groups(sets)
+        if plan is not None:
+            return None, [("pk_grouped", None, plan)]
+        shared, unique = self._split_shared_unique(sets)
+        if shared and unique:
+            plan = self._plan_groups([sets[i] for i in shared])
             if plan is not None:
-                t = time.monotonic()
-                result = self._submit_grouped(sets, plan)
-                if result is None:
-                    return lambda: False
-                return lambda: self._resolve(result, t)
-            # roots don't group — try the DUAL axis: pubkeys repeat in
-            # any adversarial unique-root flood (bounded attacker keys)
-            pk_plan = self._plan_pk_groups(sets)
-            if pk_plan is not None:
-                t = time.monotonic()
-                result = self._submit_pk_grouped(sets, pk_plan)
-                if result is None:
-                    return lambda: False
-                return lambda: self._resolve(result, t)
-            # mixed batch: peel the shared-root sets onto the grouped
-            # kernel; the singleton remainder tries pk-grouping before
-            # paying the per-set kernel
-            shared, unique = self._split_shared_unique(sets)
-            if shared and unique:
-                shared_sets = [sets[i] for i in shared]
-                sub_plan = self._plan_groups(shared_sets)
-                if sub_plan is not None:
-                    # the peeled parts also count under their own paths
-                    self.observer.planner("split", len(sets))
-                    t = time.monotonic()
-                    grouped_res = self._submit_grouped(shared_sets, sub_plan)
-                    if grouped_res is None:
-                        return lambda: False
-                    unique_sets = [sets[i] for i in unique]
-                    pk_plan = self._plan_pk_groups(unique_sets)
-                    if pk_plan is not None:
-                        pk_res = self._submit_pk_grouped(unique_sets, pk_plan)
-                        if pk_res is None:
-                            return lambda: False
-                        return lambda: (
-                            self._resolve(grouped_res, t)
-                            and self._resolve(pk_res, t)
-                        )
-                    flat = self._submit_flat(unique_sets)
-                    return lambda: self._resolve(grouped_res, t) and flat()
-        return self._submit_flat(sets)
+                pk_plan = self._plan_pk_groups([sets[i] for i in unique])
+                rest = (("per_set", unique, None) if pk_plan is None
+                        else ("pk_grouped", unique, pk_plan))
+                return "split", [("root_grouped", shared, plan), rest]
+        return None, [("per_set", None, None)]
+
+    def _priced_plan(self, sets):
+        """The cheapest plan at the calibrated prices: the sum of its
+        calls' seconds, ties to fewer calls, then to flat. Candidates:
+        flat; per root-grouped config, its `rows` fullest root runs on one
+        call and every other set flat or pk-grouped, whichever costs less
+        (`row_overflow` when any set is left over: honest committee roots
+        stay grouped beside an attacker's singletons); per pk-grouped
+        config, the whole batch where its pubkey runs fit. A grouped
+        candidate needs a key two sets share: grouping unshared keys
+        saves nothing."""
+        flat = self._flat_plan(len(sets))
+        candidates = self._pk_plans(sets)
+        roots = [s.message for s in sets]
+        for rows, lanes in self.kernels.grouped_configs:
+            runs = sorted(self._runs(roots, lanes), key=len, reverse=True)
+            top, rest = runs[:rows], runs[rows:]
+            if len(top[0]) < 2:
+                continue
+            price = self._prices[("root_grouped", (rows, lanes))]
+            if not rest:
+                candidates.append(((price, 1), None,
+                                   [("root_grouped", None, (rows, lanes, top))]))
+                continue
+            grouped = [i for run in top for i in run]
+            pos = iter(range(len(grouped)))
+            local = [[next(pos) for _ in run] for run in top]
+            left = sorted(i for run in rest for i in run)
+            (left_s, left_calls), _, [(kind, _, plan)] = _cheapest(
+                [self._flat_plan(len(left))]
+                + self._pk_plans([sets[i] for i in left])
+            )
+            candidates.append((
+                (price + left_s, 1 + left_calls), "row_overflow",
+                [("root_grouped", grouped, (rows, lanes, local)),
+                 (kind, left, plan)],
+            ))
+        best = _cheapest([flat] + candidates)
+        if best is flat and candidates:
+            return "priced_flat", flat[2]
+        return best[1], best[2]
+
+    def _flat_plan(self, n: int):
+        """The priced plan of `_submit_flat` on n sets."""
+        cap = self.kernels.buckets[-1]
+        full, rest = divmod(n, cap)
+        calls = [cap] * full + ([self.kernels.bucket_for(rest)] if rest else [])
+        seconds = sum(self._prices[("per_set", (b,))] for b in calls)
+        return (seconds, len(calls)), None, [("per_set", None, None)]
+
+    def _pk_plans(self, sets):
+        """Priced plans of the whole batch on each pk-grouped config whose
+        pubkey runs fit it, where some pubkey repeats."""
+        configs = [(rows, lanes) for rows, lanes
+                   in self.kernels.pk_grouped_configs
+                   if len(sets) <= rows * lanes]
+        try:
+            keys = [s.pubkey.to_bytes() for s in sets] if configs else []
+        except (bls_api.BlsError, ValueError):
+            return []  # the flat path reports the malformed set as False
+        out = []
+        for rows, lanes in configs:
+            runs = self._runs(keys, lanes)
+            if len(runs) <= rows and len(runs) < len(keys):
+                out.append(((self._prices[("pk_grouped", (rows, lanes))], 1),
+                            None, [("pk_grouped", None, (rows, lanes, runs))]))
+        return out
 
     def _resolve(self, result, t_submit: float | None = None) -> bool:
         """Block on one device verdict, timing the wait (`device_wait`
@@ -1596,75 +1671,71 @@ class TpuBlsVerifier:
             and rows % self._mesh.size == 0
         )
 
-    def _submit_grouped_mesh(self, sets, plan):
-        """Sharded grouped dispatch across the serving mesh. With device
-        decompression on (the default), signatures stay WIRE BYTES all
-        the way onto the mesh — the `*_raw` sharded twins decode each
-        chip's row slice on device, so the host marshal is a pure byte
-        scatter (zero-copy ingest, same contract as the single-device
-        raw path). LODESTAR_TPU_DEVICE_DECOMPRESS=0 keeps the pooled
-        C-tier limb marshal. Falls back to the matching single-device
-        kernel if the mesh shrank between the eligibility check and the
-        dispatch."""
-        from .mesh import NOT_SHARDED
-
-        if self._device_decompress:
-            with self.observer.stage("marshal"):
-                marshalled = self._marshal_grouped(sets, plan, raw=True)
-            if marshalled is None:
-                return None
-            g, sig_raw = marshalled
-            with self.observer.stage("rand"):
-                a_bits, b_bits = _rand_pairs(g.valid.shape, self._custom_rng)
-            with self.observer.stage("dispatch"):
-                result = self._mesh.dispatch_grouped_raw(
-                    g, sig_raw, a_bits, b_bits
-                )
-                if result is NOT_SHARDED:
-                    result = self.kernels.verify_grouped_raw(
-                        g, sig_raw, a_bits, b_bits
-                    )
-            return result
-        with self.observer.stage("marshal"):
-            g = self._marshal_grouped(sets, plan)
-        if g is None:
-            return None
-        with self.observer.stage("rand"):
-            a_bits, b_bits = _rand_pairs(g.valid.shape, self._custom_rng)
-        with self.observer.stage("dispatch"):
-            result = self._mesh.dispatch_grouped(g, a_bits, b_bits)
-            if result is NOT_SHARDED:
-                result = self.kernels.verify_grouped(g, a_bits, b_bits)
-        return result
-
-    def _submit_grouped(self, sets, plan):
-        """Dispatch one grouped-kernel batch; None marks an invalid set
-        (caller reports False)."""
+    def _submit_grouped(self, sets, plan, kind="root_grouped"):
+        """Dispatch one grouped-kernel batch, `kind` root_grouped or
+        pk_grouped; None marks an invalid set (caller reports False).
+        With device decompression on (the default) signatures stay wire
+        bytes all the way to the kernel."""
         self.observer.planner(
-            "root_grouped", len(sets), group_sizes=[len(r) for r in plan[2]]
+            kind, len(sets), group_sizes=[len(r) for r in plan[2]]
         )
-        if self._mesh_shardable(plan[0]):
-            return self._submit_grouped_mesh(sets, plan)
-        if self._device_decompress:
-            with self.observer.stage("marshal"):
-                marshalled = self._marshal_grouped(sets, plan, raw=True)
-            if marshalled is None:
-                return None
-            g, sig_raw = marshalled
-            with self.observer.stage("rand"):
-                a_bits, b_bits = _rand_pairs(g.valid.shape, self._custom_rng)
-            with self.observer.stage("dispatch"):
-                return self.kernels.verify_grouped_raw(
-                    g, sig_raw, a_bits, b_bits
-                )
+        marshal, dispatch = {
+            "root_grouped": (self._marshal_grouped, self._dispatch_grouped),
+            "pk_grouped": (self._marshal_pk_grouped,
+                           self._dispatch_pk_grouped),
+        }[kind]
+        raw = self._device_decompress
         with self.observer.stage("marshal"):
-            g = self._marshal_grouped(sets, plan)
-        if g is None:
+            marshalled = marshal(sets, plan, raw=raw)
+        if marshalled is None:
             return None
+        g, sig_raw = marshalled if raw else (marshalled, None)
         with self.observer.stage("rand"):
             a_bits, b_bits = _rand_pairs(g.valid.shape, self._custom_rng)
         with self.observer.stage("dispatch"):
+            return dispatch(g, sig_raw, a_bits, b_bits)
+
+    def _dispatch_grouped(self, g, sig_raw, a_bits, b_bits):
+        """The root-grouped kernel call, sharded over the serving mesh
+        when its rows split across it (falling back to one device if the
+        mesh shrank meanwhile); `sig_raw` None = host-decompressed."""
+        if self._mesh_shardable(g.valid.shape[0]):
+            from .mesh import NOT_SHARDED
+
+            result = (
+                self._mesh.dispatch_grouped(g, a_bits, b_bits)
+                if sig_raw is None
+                else self._mesh.dispatch_grouped_raw(g, sig_raw, a_bits, b_bits)
+            )
+            if result is not NOT_SHARDED:
+                return result
+        if sig_raw is None:
             return self.kernels.verify_grouped(g, a_bits, b_bits)
+        return self.kernels.verify_grouped_raw(g, sig_raw, a_bits, b_bits)
+
+    def _dispatch_pk_grouped(self, g, sig_raw, a_bits, b_bits):
+        """The pk-grouped kernel call, routed as `_dispatch_grouped`."""
+        if self._mesh_shardable(g.valid.shape[0]):
+            from .mesh import NOT_SHARDED
+
+            result = (
+                self._mesh.dispatch_pk_grouped(g, a_bits, b_bits)
+                if sig_raw is None
+                else self._mesh.dispatch_pk_grouped_raw(
+                    g, sig_raw, a_bits, b_bits
+                )
+            )
+            if result is not NOT_SHARDED:
+                return result
+        if sig_raw is None:
+            return self.kernels.verify_pk_grouped(g, a_bits, b_bits)
+        return self.kernels.verify_pk_grouped_raw(g, sig_raw, a_bits, b_bits)
+
+    def _dispatch_per_set(self, arrs, sig_raw, r_bits):
+        """The per-set kernel call; `sig_raw` None = host-decompressed."""
+        if sig_raw is None:
+            return self.kernels.verify_batch(arrs, r_bits)
+        return self.kernels.verify_batch_raw(arrs, sig_raw, r_bits)
 
     def _submit_flat(self, sets):
         """Per-set kernel dispatch (chunked to the largest bucket);
@@ -1673,32 +1744,19 @@ class TpuBlsVerifier:
         if sets:
             self.observer.planner("per_set", len(sets))
         cap = self.kernels.buckets[-1]
-        use_raw = self._device_decompress and self._native_eligible(sets)
+        raw = self._device_decompress and self._native_eligible(sets)
         results = []
         t_submit = time.monotonic()
         for lo in range(0, max(len(sets), 1), cap):
-            chunk = sets[lo : lo + cap]
-            if use_raw:
-                with self.observer.stage("marshal"):
-                    marshalled = self._marshal(chunk, raw=True)
-                if marshalled is None:
-                    return lambda: False
-                arrs, sig_raw = marshalled
-                with self.observer.stage("rand"):
-                    r_bits = _rand_bits(arrs.pk_x.shape[0], self._rng)
-                with self.observer.stage("dispatch"):
-                    results.append(
-                        self.kernels.verify_batch_raw(arrs, sig_raw, r_bits)
-                    )
-                continue
             with self.observer.stage("marshal"):
-                arrs = self._marshal(chunk)
-            if arrs is None:
+                marshalled = self._marshal(sets[lo : lo + cap], raw=raw)
+            if marshalled is None:
                 return lambda: False
+            arrs, sig_raw = marshalled if raw else (marshalled, None)
             with self.observer.stage("rand"):
                 r_bits = _rand_bits(arrs.pk_x.shape[0], self._rng)
             with self.observer.stage("dispatch"):
-                results.append(self.kernels.verify_batch(arrs, r_bits))
+                results.append(self._dispatch_per_set(arrs, sig_raw, r_bits))
         return lambda: all(self._resolve(r, t_submit) for r in results)
 
     def verify_signature_sets_individual(self, sets) -> list[bool]:

@@ -83,12 +83,16 @@ def test_counter_checks_flag_every_failure_mode(smoke):
 
 
 def test_stack_wires_the_sizing_through_every_layer(monkeypatch):
+    import time
+
+    import numpy as np
+
     from lodestar_tpu.chain import verifier_stack
     from lodestar_tpu.chain.bls_verifier import CpuBlsVerifier, DeviceBlsVerifier
     from lodestar_tpu.chain.dispatcher import BlsLaneDispatcher
     from lodestar_tpu.chain.supervisor import SupervisedBlsVerifier
     from lodestar_tpu.observability.stages import PipelineMetrics
-    from lodestar_tpu.parallel.verifier import TpuBlsVerifier
+    from lodestar_tpu.parallel.verifier import BatchVerifier, TpuBlsVerifier
 
     # the stack compiles every kernel its device tier can dispatch
     # before it is returned; here only the list of what it would compile
@@ -101,6 +105,20 @@ def test_stack_wires_the_sizing_through_every_layer(monkeypatch):
         return list(compiled)
 
     monkeypatch.setattr(TpuBlsVerifier, "precompile", precompile)
+    # ...and times each kernel shape through stand-ins for its calls
+    timed = []
+
+    def call(name):
+        def run(self, *args):
+            timed.append(name)
+            time.sleep(0.001)
+            return np.bool_(False)
+        return run
+
+    for name in ("verify_batch", "verify_batch_raw", "verify_grouped",
+                 "verify_grouped_raw", "verify_pk_grouped",
+                 "verify_pk_grouped_raw"):
+        monkeypatch.setattr(BatchVerifier, name, call(name))
     stack = verifier_stack.build_verifier_stack(PipelineMetrics())
     try:
         assert isinstance(stack.dispatcher, BlsLaneDispatcher)
@@ -125,6 +143,18 @@ def test_stack_wires_the_sizing_through_every_layer(monkeypatch):
         assert stack.compiled == compiled == [
             "grouped_raw", "pk_grouped_raw", "batch_raw", "bisect_tree",
             "bisect_probe",
+        ]
+        # then priced: every shape the planner chooses among, through the
+        # raw entries a dispatch takes, the per-set call on its second run
+        (bucket,) = verifier_stack.BUCKETS
+        assert set(stack.prices) == {
+            ("per_set", (bucket,)), ("root_grouped", (rows, lanes)),
+            ("pk_grouped", kernels.pk_grouped_configs[0]),
+        }
+        assert all(s > 0 for s in stack.prices.values())
+        assert stack.device._inner._prices == stack.prices
+        assert timed == ["verify_batch_raw"] * 2 + [
+            "verify_grouped_raw", "verify_pk_grouped_raw",
         ]
     finally:
         stack.close()

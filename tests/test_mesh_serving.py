@@ -155,6 +155,55 @@ def test_compile_target_is_the_verifier_its_dispatch_uses(kind, key,
     assert calls[0]._run is fn
 
 
+def test_calibration_prices_grouped_shapes_through_the_mesh():
+    """A grouped shape the serving mesh takes is priced through its
+    sharded dispatch, at the sharded call's own cost; a shape whose rows
+    do not split over the mesh, and the per-set buckets, are priced on
+    one device, as a dispatch of theirs runs."""
+    import time
+
+    import numpy as np
+
+    from lodestar_tpu.parallel.verifier import TpuBlsVerifier
+
+    class Sharded(_StubVerifier):
+        def submit(self, *args):
+            self.submits += 1
+            time.sleep(0.01)
+            return np.bool_(False)
+
+    calls = []
+
+    def factory(kind, devices, axis):
+        calls.append(Sharded(kind, devices, axis))
+        return calls[-1]
+
+    mesh = BlsMeshDispatcher([f"dev{i}" for i in range(4)],
+                             observer=PipelineMetrics(),
+                             verifier_factory=factory)
+    v = TpuBlsVerifier(buckets=(4,), grouped_configs=((6, 4), (8, 4)),
+                       pk_grouped_configs=((8, 4),), mesh=mesh)
+    single = []
+
+    def one_device(name, seconds):
+        def call(*args):
+            single.append(name)
+            time.sleep(seconds)
+            return np.bool_(False)
+        return call
+
+    v.kernels.verify_batch_raw = one_device("per_set", 0.001)
+    v.kernels.verify_grouped_raw = one_device("grouped", 0.05)
+    v.kernels.verify_pk_grouped_raw = one_device("pk_grouped", 0.05)
+    prices = v.calibrate()
+    assert [(c.kind, c.submits) for c in calls] == [
+        ("grouped_raw", 1), ("pk_grouped_raw", 1),
+    ]
+    assert single == ["per_set", "per_set", "grouped"]  # 6 rows: one device
+    assert prices[("root_grouped", (8, 4))] < prices[("root_grouped", (6, 4))]
+    assert prices[("pk_grouped", (8, 4))] < 0.05
+
+
 def test_dispatch_grouped_raw_routes_and_counts():
     """ISSUE 15: the zero-copy raw twins route through the same verifier
     cache and chip accounting as the limb kernels, under their own

@@ -139,6 +139,113 @@ def test_planner_counters_per_set_and_individual_paths():
     assert snap["rounds"] == 0 and snap["probes"] == 0
 
 
+def _root_sets(sizes, salt=0):
+    """One run of sets per signing root, `sizes[k]` signers on root k,
+    every signer a distinct key."""
+    out = []
+    for k, n in enumerate(sizes):
+        msg = bytes([k, salt & 0xFF]) + b"\x5a" * 30
+        for _ in range(n):
+            sk = bls.interop_secret_key(len(out) + salt)
+            out.append(bls.SignatureSet(
+                pubkey=sk.to_public_key(), message=msg,
+                signature=sk.sign(msg).to_bytes(),
+            ))
+    return out
+
+
+def _priced(p, per_set, grouped, pk=100.0, **shapes):
+    """A verifier of kernel `shapes` on `p`, passed a price list of
+    `per_set`, `grouped` and `pk` seconds a call for every shape of each
+    kernel, its dispatches stubbed."""
+    from lodestar_tpu.parallel.verifier import BatchVerifier, TpuBlsVerifier
+
+    k = BatchVerifier(**shapes)
+    prices = {
+        **{("per_set", (b,)): per_set for b in k.buckets},
+        **{("root_grouped", c): grouped for c in k.grouped_configs},
+        **{("pk_grouped", c): pk for c in k.pk_grouped_configs},
+    }
+    v = TpuBlsVerifier(observer=p, prices=prices, **shapes)
+    _stub_kernels(v)
+    return v
+
+
+@needs_native
+@pytest.mark.parametrize("grouped_s,path", [
+    (10.0, "priced_flat"),  # one grouped call costs more than one flat call
+    (0.5, "root_grouped"),
+])
+def test_planner_counters_priced_flat_path(grouped_s, path):
+    """A groupable batch goes flat, counted `priced_flat` as well as
+    `per_set`, when its per-set call costs less than the grouped call,
+    and root-grouped when the grouped call costs less."""
+    p = PipelineMetrics()
+    v = _priced(p, 1.0, grouped_s)
+    assert v.verify_signature_sets(_sets(8))  # one shared root
+    decisions = p.planner_snapshot()["decisions"]
+    if path == "priced_flat":
+        assert decisions == {"priced_flat": 1, "per_set": 1}
+    else:
+        assert decisions == {"root_grouped": 1}
+    assert p.planner_sets.value(path=path) == 8
+
+
+@needs_native
+def test_planner_counters_row_overflow_path():
+    """One shared root more than the grouped rows: the fullest roots ride
+    one grouped call, the rest go flat, counted `row_overflow` for the
+    batch and under their own paths for the parts."""
+    p = PipelineMetrics()
+    # flat: 3 calls, 3.0 s; grouped 4 + 3 sets and 2 flat: 1.5 + 1.0 s
+    v = _priced(p, 1.0, 1.5, buckets=(4,), grouped_configs=((2, 4),),
+                pk_grouped_configs=((2, 4),))
+    assert v.verify_signature_sets(_root_sets([2, 4, 3]))
+    snap = p.planner_snapshot()
+    assert snap["decisions"] == {
+        "row_overflow": 1, "root_grouped": 1, "per_set": 1,
+    }
+    assert snap["sets"] == {"row_overflow": 9, "root_grouped": 7, "per_set": 2}
+    # the two grouped rows are the fullest roots: 4 and 3 sets
+    assert p.planner_group_size._totals[()] == 2
+    assert p.planner_group_size._sums[()] == 7
+
+
+@needs_native
+def test_planner_price_gauge_holds_the_calibrated_prices():
+    """`calibrate()` times one call per kernel shape and publishes each
+    price on `lodestar_bls_planner_price_seconds{kernel,shape}`; the
+    planner then prices batches with them."""
+    from lodestar_tpu.parallel.verifier import TpuBlsVerifier
+
+    p = PipelineMetrics()
+    v = TpuBlsVerifier(buckets=(4, 8), grouped_configs=((2, 4),),
+                       pk_grouped_configs=((2, 4),), observer=p)
+    _stub_kernels(v)
+    for name in ("verify_batch_raw", "verify_grouped_raw",
+                 "verify_pk_grouped_raw"):
+        setattr(v.kernels, name,
+                lambda *a, **kw: time.sleep(0.002) or np.bool_(False))
+    assert v._prices is None  # uncalibrated: the unpriced order
+    prices = v.calibrate()
+    assert set(prices) == {
+        ("per_set", (4,)), ("per_set", (8,)), ("root_grouped", (2, 4)),
+        ("pk_grouped", (2, 4)),
+    }
+    assert v._prices == prices
+    for (kernel, shape), seconds in prices.items():
+        assert seconds >= 0.002
+        label = "x".join(map(str, shape))
+        assert p.planner_price.value(kernel=kernel, shape=label) == seconds
+    text = p.registry.expose()
+    assert ('lodestar_bls_planner_price_seconds{kernel="root_grouped",'
+            'shape="2x4"}') in text
+    # a price list passed in is published the same way
+    q = PipelineMetrics()
+    TpuBlsVerifier(observer=q, prices={("per_set", (4,)): 0.25})
+    assert q.planner_price.value(kernel="per_set", shape="4") == 0.25
+
+
 @needs_native
 def test_bisect_counters_on_failed_root(monkeypatch):
     """A failed tree root walks the host bisection driver: rounds and

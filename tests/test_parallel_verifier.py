@@ -268,6 +268,99 @@ def test_pubkey_cache_hits_and_verdict_stable(grouped_verifier):
     assert grouped_verifier.verify_signature_sets(sets) is False
 
 
+# --- the priced planner: each batch on its cheapest plan ---------------------
+
+
+def _priced_verifier(per_set_4, per_set_16, grouped):
+    """The grouped-path shapes, passed a price list in seconds a call
+    (the pk-grouped kernel priced out of every plan)."""
+    from lodestar_tpu.observability.stages import PipelineMetrics
+
+    return TpuBlsVerifier(
+        buckets=(4, 16), grouped_configs=((4, 4),),
+        pk_grouped_configs=((4, 4),), rng=_det_rng,
+        device_decompress=False, observer=PipelineMetrics(),
+        prices={("per_set", (4,)): per_set_4, ("per_set", (16,)): per_set_16,
+                ("root_grouped", (4, 4)): grouped,
+                ("pk_grouped", (4, 4)): 100.0},
+    )
+
+
+# 17 sets on 5 roots (4, 4, 3, 3, 3 sets; root k holds sets i ≡ k mod 5)
+# against 4 grouped rows: flat is 2 calls (4 + 1 s), the 4 fullest roots
+# grouped with 3 sets over 1.5 + 1 s
+_OVERFLOW = {"row_overflow": 1, "root_grouped": 1, "per_set": 1}
+_OVERFLOW_SETS = {"row_overflow": 17, "root_grouped": 14, "per_set": 3}
+
+
+@pytest.mark.parametrize("sets,prices,decisions,sets_by_path,verdict", [
+    # (a) 10 sets on 3 roots: one flat call against one grouped call
+    (lambda: _make_shared_root_sets(10, 3), (1.0, 1.0, 5.0),
+     {"priced_flat": 1, "per_set": 1}, {"priced_flat": 10, "per_set": 10},
+     True),
+    (lambda: _make_shared_root_sets(10, 3), (1.0, 1.0, 0.5),
+     {"root_grouped": 1}, {"root_grouped": 10}, True),
+    # (b) rows_cap + 1 shared roots: the fullest 4 rows grouped (14 sets,
+    # the most any 4 rows hold), the last root's 3 sets flat
+    (lambda: _make_shared_root_sets(17, 5), (1.0, 4.0, 1.5),
+     _OVERFLOW, _OVERFLOW_SETS, True),
+    # (c) an invalid set in the grouped rows (root 0), in the overflow
+    # (root 4)
+    (lambda: _tamper(_make_shared_root_sets(17, 5), 0), (1.0, 4.0, 1.5),
+     _OVERFLOW, _OVERFLOW_SETS, False),
+    (lambda: _tamper(_make_shared_root_sets(17, 5), 4), (1.0, 4.0, 1.5),
+     _OVERFLOW, _OVERFLOW_SETS, False),
+    # (d) a unique-root flood beside committee roots: the shared roots
+    # stay grouped (flat: 2 calls, 8 s; grouped + 1 flat call: 5.5 s)
+    (lambda: _make_shared_root_sets(16, 4) + _make_unique_root_sets(8),
+     (1.0, 4.0, 1.5), {"row_overflow": 1, "root_grouped": 1, "per_set": 1},
+     {"row_overflow": 24, "root_grouped": 16, "per_set": 8}, True),
+], ids=["a_flat_cheaper", "a_grouped_cheaper", "b_row_overflow",
+        "c_invalid_in_rows", "c_invalid_in_overflow", "d_unique_flood"])
+def test_priced_planner_dispatches_the_cheapest_plan(
+        sets, prices, decisions, sets_by_path, verdict):
+    v = _priced_verifier(*prices)
+    sets = sets()
+    assert v.verify_signature_sets(sets) is verdict
+    assert bls.verify_signature_sets(sets) is verdict  # the oracle agrees
+    snap = v.observer.planner_snapshot()
+    assert snap["decisions"] == decisions
+    assert snap["sets"] == sets_by_path
+
+
+def test_stack_build_prices_every_kernel_shape_it_compiled(monkeypatch):
+    """`build_verifier_stack` at tiny shapes times each compiled kernel
+    shape the planner chooses among on this device: a positive price
+    for every one."""
+    from lodestar_tpu.chain.verifier_stack import build_verifier_stack
+    from lodestar_tpu.observability.stages import PipelineMetrics
+    from lodestar_tpu.parallel import verifier
+
+    # one compile at a time: concurrent XLA:CPU compiles in a process that
+    # already holds kernels run out of memory maps
+    monkeypatch.setattr(verifier, "run_concurrently",
+                        lambda calls, workers=None: [c() for c in calls])
+    pipeline = PipelineMetrics()
+    stack = build_verifier_stack(pipeline, buckets=(4,),
+                                 grouped_configs=((2, 4),),
+                                 pk_grouped_configs=((2, 4),))
+    try:
+        planned = stack.device._inner.kernel_shapes(
+            ("grouped", "pk_grouped", "per_set")
+        )
+        assert len(planned) == len(stack.prices) == 3
+        assert set(stack.prices) == {
+            ("per_set", (4,)), ("root_grouped", (2, 4)),
+            ("pk_grouped", (2, 4)),
+        }
+        assert all(s > 0 for s in stack.prices.values())
+        assert pipeline.planner_price.value(
+            kernel="root_grouped", shape="2x4"
+        ) == stack.prices[("root_grouped", (2, 4))]
+    finally:
+        stack.close()
+
+
 # --- device-decompression path (raw signature bytes on device) ---------------
 
 
